@@ -101,6 +101,10 @@ def main() -> None:
         ap.error("--full and --tiny are mutually exclusive")
     quick = not args.full
 
+    from repro.launch.runtime import enable_compile_cache
+
+    enable_compile_cache()
+
     from benchmarks import (
         bench_convergence,
         bench_kernels,

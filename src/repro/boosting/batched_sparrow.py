@@ -31,6 +31,7 @@ Deviations from the unbatched worker, both bounded and test-pinned:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import NamedTuple
 
@@ -77,8 +78,11 @@ class BatchedSparrowState(NamedTuple):
     cert: jnp.ndarray  # (W,) f32
     scanner: ScannerState  # leaves (W, ...)
     sample: SampleState  # leaves (W, m, ...)
-    disk_margin: jnp.ndarray  # (W, n)
-    disk_t: jnp.ndarray  # (W, n) i32
+    disk_margin: jnp.ndarray  # (W, n) H_{disk_t}(x) over the disk set
+    #: (W,) i32 stump count the disk margins are current to (the same for
+    #: every example: a resample refreshes the whole set); -1 = margins
+    #: discarded by an adoption, read as H_0 = 0 at the next resample
+    disk_t: jnp.ndarray
     key: jax.Array  # (W, 2) PRNG keys
     needs_resample: jnp.ndarray  # (W,) bool
     fires: jnp.ndarray  # (W,) i32
@@ -137,7 +141,7 @@ class BatchedSparrowWorker(SparrowWorkerBase):
             scanner=scanner,
             sample=sample,
             disk_margin=jnp.zeros((n_workers, self.n), jnp.float32),
-            disk_t=jnp.zeros((n_workers, self.n), jnp.int32),
+            disk_t=jnp.zeros((n_workers,), jnp.int32),
             key=keys,
             needs_resample=jnp.zeros((n_workers,), bool),
             fires=zeros_i,
@@ -172,6 +176,19 @@ class BatchedSparrowWorker(SparrowWorkerBase):
 
     def payload_bytes(self) -> int:
         return model_payload_bytes(empty_model(self.config.capacity))
+
+    def shared_data(self) -> tuple[jnp.ndarray, jnp.ndarray]:
+        """The disk dataset, read by the resample segment."""
+        return self.xb, self.y
+
+    @contextlib.contextmanager
+    def bind_shared_data(self, data: tuple[jnp.ndarray, jnp.ndarray]):
+        held = self.xb, self.y
+        self.xb, self.y = data
+        try:
+            yield
+        finally:
+            self.xb, self.y = held
 
     # ----- one scan segment for every masked worker -------------------
     def scan_round(
@@ -233,19 +250,24 @@ class BatchedSparrowWorker(SparrowWorkerBase):
         return new_state, cost, fired
 
     # ----- resample segment (rare; sequential over workers so the full
-    # disk pass never materializes a (W, n, T) intermediate) ------------
+    # disk pass never materializes a (W, n, T) intermediate, and in
+    # place, so the (W, ...) state is never copied) ---------------------
     def resample_round(
         self, state: BatchedSparrowState, do: jnp.ndarray
     ) -> tuple[BatchedSparrowState, jnp.ndarray]:
         cfg = self.config
 
         def _resample_one(st: BatchedSparrowState):
-            delta = predict_margin_delta(st.model, self.xb, st.disk_t)
-            evals = jnp.sum(
-                jnp.minimum(st.model.count - st.disk_t, st.model.capacity)
+            stale = st.disk_t < 0
+            t_from = jnp.maximum(st.disk_t, 0)
+            delta = predict_margin_delta(
+                st.model, self.xb, jnp.full((self.n,), t_from, jnp.int32)
+            )
+            evals = (
+                self.n * jnp.minimum(st.model.count - t_from, st.model.capacity)
             ).astype(jnp.float32)
-            disk_margin = st.disk_margin + delta
-            disk_t = jnp.full_like(st.disk_t, st.model.count)
+            disk_margin = jnp.where(stale, 0.0, st.disk_margin) + delta
+            disk_t = st.model.count
             key, sub = jax.random.split(st.key)
             sample = draw_sample(sub, self.xb, self.y, st.model, disk_margin, cfg.sample_size)
             cost = self.n * cfg.disk_read_cost + STUMP_EVAL_COST * evals
@@ -267,14 +289,17 @@ class BatchedSparrowWorker(SparrowWorkerBase):
             )
             return new, jnp.asarray(cost, jnp.float32)
 
-        def _one(per):
-            st, flag = per
-            return jax.lax.cond(
-                flag, _resample_one, lambda s: (s, jnp.zeros((), jnp.float32)), st
+        def _one(i, carry):
+            states, costs = carry
+            st = jax.tree_util.tree_map(lambda a: a[i], states)
+            new, cost = jax.lax.cond(
+                do[i], _resample_one, lambda s: (s, jnp.zeros((), jnp.float32)), st
             )
+            states = jax.tree_util.tree_map(lambda a, v: a.at[i].set(v), states, new)
+            return states, costs.at[i].set(cost)
 
-        new_state, cost = jax.lax.map(_one, (state, do))
-        return new_state, cost
+        costs = jnp.zeros(do.shape, jnp.float32)
+        return jax.lax.fori_loop(0, do.shape[0], _one, (state, costs))
 
     # ----- adoption (interrupt + replace (H, L)) -----------------------
     def adopt_batch(
@@ -306,15 +331,14 @@ class BatchedSparrowWorker(SparrowWorkerBase):
                 margin_l=m_new,
                 t_l=jnp.full_like(st.sample.t_l, new_model.count),
             )
-            keep_disk = p >= st.disk_t[0]
-            disk_margin = jnp.where(keep_disk, st.disk_margin, 0.0)
-            disk_t = jnp.where(keep_disk, st.disk_t, 0)
+            # the disk margins survive when the shared prefix covers
+            # them; otherwise they are marked stale, not rewritten
+            disk_t = jnp.where(p >= st.disk_t, st.disk_t, -1)
             cost = STUMP_EVAL_COST * evals * cfg.mem_read_cost
             new = st._replace(
                 model=new_model,
                 cert=jnp.asarray(new_cert, jnp.float32),
                 sample=sample,
-                disk_margin=disk_margin,
                 disk_t=disk_t,
                 scanner=reset_after_fire(st.scanner, True, cfg.scanner),
             )

@@ -43,7 +43,8 @@ class ScannerConfig(NamedTuple):
     C: float = 1.0
     delta: float = 1e-6
     #: route histogram accumulation through the Pallas edge_scan kernel
-    #: (interpret mode on CPU; compiled Mosaic on a real TPU).
+    #: (the one switch for it; interpret mode off the TPU, compiled
+    #: Mosaic on it).
     use_kernel: bool = False
     #: gamma policy after a successful fire:
     #:   "keep"  - pseudocode: stay at the collapsed level (tiny alphas),

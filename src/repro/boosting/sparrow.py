@@ -94,7 +94,6 @@ class SparrowConfig:
     ess_threshold: float = 0.1  # resample when n_eff/m drops below this
     keep_gamma_on_fire: bool = True  # False = paper pseudocode (reset to gamma0)
     n_workers: int = 1  # for feature ownership
-    use_kernel: bool = False  # route the chunk scan through the Pallas kernel
     #: beyond-paper: each feature owned by r workers (r>1 keeps the full
     #: hypothesis space reachable when workers fail-stop; EXPERIMENTS.md
     #: §Repro shows r=1 loses certificate progress under failures)

@@ -110,8 +110,10 @@ from repro.core.protocol import accepts, improves
 from repro.core.result import SimResult, TrafficCounters
 from repro.core.worker import (
     BatchedTMSNWorker,
+    bind_shared_data,
     has_resample_hooks,
     resolve_payload_bytes,
+    shared_data,
 )
 
 #: multiplier applied to the warm-up probe's measured
@@ -1084,6 +1086,8 @@ class TMSNEngine:
         #: payload_bytes() when defined, else derived from the exported
         #: model pytree via jax.eval_shape (cannot drift from reality)
         self._payload_bytes = resolve_payload_bytes(worker, w, config.seed)
+        #: the worker's shared read-only arrays, a step argument
+        self._shared = shared_data(worker)
 
     # ------------------------------------------------------------------
     # dispatch chunking: K rounds per jitted call via lax.scan
@@ -1132,16 +1136,19 @@ class TMSNEngine:
     def _build_chunk(self, length: int):
         """Jitted ``state -> (state, RoundInfo stacked over length)``;
         the sharded engine overrides this to run the scan inside
-        ``shard_map``."""
+        ``shard_map``. The worker's shared read-only data enters as an
+        argument, so it is never baked into the program as a constant."""
         body = self._chunk_body(self._round_step, jnp.any)
 
-        def chunk(state: EngineState):
-            (state, _), infos = jax.lax.scan(
-                body, (state, jnp.zeros((), bool)), None, length=length
-            )
+        def chunk(state: EngineState, shared: Any):
+            with bind_shared_data(self.worker, shared):
+                (state, _), infos = jax.lax.scan(
+                    body, (state, jnp.zeros((), bool)), None, length=length
+                )
             return state, infos
 
-        return jax.jit(chunk)
+        step = jax.jit(chunk)
+        return lambda state: step(state, self._shared)
 
     def _chunk_fn(self, length: int):
         fn = self._chunks.get(length)

@@ -157,8 +157,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.engine import (
     EngineConfig,
@@ -171,7 +170,7 @@ from repro.core.engine import (
     _queue_push_candidates,
 )
 from repro.core.protocol import accepts, improves
-from repro.core.worker import BatchedTMSNWorker, export_payload_rows
+from repro.core.worker import BatchedTMSNWorker, bind_shared_data, export_payload_rows
 
 
 class _ShardConsts(NamedTuple):
@@ -215,6 +214,8 @@ class ShardedTMSNEngine(TMSNEngine):
             )
         self._w_local = config.n_workers // self._n_dev
         super().__init__(worker, config)
+        # place the shared data on every device once, not per dispatch
+        self._shared = jax.device_put(self._shared, NamedSharding(mesh, P()))
         if self._n_pods > 1:
             # (W,) pod of each global worker id — closure-captured by
             # the shard-mapped step (replicated; a few hundred int32s),
@@ -274,22 +275,25 @@ class ShardedTMSNEngine(TMSNEngine):
             axes = ("workers",) if self._n_pods == 1 else ("pod", "workers")
             return jax.lax.psum(jnp.any(x).astype(jnp.int32), axes) > 0
 
-        def chunk_local(state: EngineState, consts: _ShardConsts):
+        def chunk_local(state: EngineState, consts: _ShardConsts, shared):
             body = self._chunk_body(
                 lambda st: self._sharded_round_step(st, consts), _any_shard
             )
-            (state, _), infos = jax.lax.scan(
-                body, (state, jnp.zeros((), bool)), None, length=length
-            )
+            with bind_shared_data(self.worker, shared):
+                (state, _), infos = jax.lax.scan(
+                    body, (state, jnp.zeros((), bool)), None, length=length
+                )
             return state, infos
 
+        # the worker's shared data is replicated: every device reads all of it
+        shared_specs = jax.tree_util.tree_map(lambda _: P(), self._shared)
         step = jax.jit(
-            shard_map(
+            jax.shard_map(
                 chunk_local,
                 mesh=mesh,
-                in_specs=(state_specs, consts_specs),
+                in_specs=(state_specs, consts_specs, shared_specs),
                 out_specs=(state_specs, infos_specs),
-                check_rep=False,
+                check_vma=False,
             )
         )
         consts = _ShardConsts(
@@ -300,7 +304,7 @@ class ShardedTMSNEngine(TMSNEngine):
             delay_t=jnp.transpose(self._delay),
             join_round=self._join_round,
         )
-        return lambda state: step(state, consts)
+        return lambda state: step(state, consts, self._shared)
 
     def _init_state(self) -> EngineState:
         state = super()._init_state()

@@ -52,7 +52,8 @@ Contract requirements (the engines silently assume all of them):
 
 from __future__ import annotations
 
-from typing import Any, Protocol
+import contextlib
+from typing import Any, ContextManager, Protocol
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +67,8 @@ __all__ = [
     "export_payload_rows",
     "payload_bytes_from_export",
     "resolve_payload_bytes",
+    "shared_data",
+    "bind_shared_data",
 ]
 
 
@@ -176,6 +179,21 @@ class BatchedTMSNWorker(Protocol):
         format differs from the exported leaves."""
         raise NotImplementedError  # engines derive via resolve_payload_bytes
 
+    # ----- optional: shared read-only inputs (no-op defaults) ----------
+    def shared_data(self) -> Any:
+        """Large read-only arrays the round methods read but no single
+        worker owns (Sparrow's disk dataset), as a pytree. The engines
+        pass them into each jitted chunk as arguments: closed over, jit
+        would embed them in the compiled program as constants. ``None``
+        (this default) = nothing shared."""
+        return None
+
+    def bind_shared_data(self, data: Any) -> ContextManager:
+        """Context in which the round methods read ``data`` (traced
+        stand-ins for :meth:`shared_data`) instead of the arrays the
+        worker holds. The engines enter it while tracing a chunk."""
+        return contextlib.nullcontext()
+
 
 def masked_rows(cond: jnp.ndarray, new: Any, old: Any) -> Any:
     """Per-worker select over a stacked pytree: broadcast the ``(W,)``
@@ -206,6 +224,20 @@ def export_payload_rows(worker: BatchedTMSNWorker, state: Any, rows: jnp.ndarray
     if hook is not None:
         return hook(state, rows)
     return jax.tree_util.tree_map(lambda a: a[rows], worker.export_models(state))
+
+
+def shared_data(worker: BatchedTMSNWorker) -> Any:
+    """The worker's optional :meth:`~BatchedTMSNWorker.shared_data`, or
+    ``None`` for workers that omit it."""
+    hook = getattr(worker, "shared_data", None)
+    return hook() if callable(hook) else None
+
+
+def bind_shared_data(worker: BatchedTMSNWorker, data: Any) -> ContextManager:
+    """The worker's optional :meth:`~BatchedTMSNWorker.bind_shared_data`
+    context, or a no-op one for workers that omit it."""
+    hook = getattr(worker, "bind_shared_data", None)
+    return hook(data) if callable(hook) else contextlib.nullcontext()
 
 
 def payload_bytes_from_export(

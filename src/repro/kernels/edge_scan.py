@@ -12,9 +12,13 @@ TPU adaptation (DESIGN.md §3): recast the histogram scatter as a
                =  (wy^T @ P)[j, b],   P[i, (j,b)] = [xb[i,j] == b]
 
 Each grid step loads one (tile_n, d) block of binned features into
-VMEM, builds the one-hot P on the VPU, and contracts against the
-weight vector on the MXU, accumulating into a resident (d, B) output
-block. The stopping-rule scalars (W = sum|w|, V = sum w^2, T = sum wy)
+VMEM and builds P directly on a (tile_n, d*B) lane axis: one 0/1
+matmul copies feature j onto lanes j*B .. j*B+B-1, and a compare with
+each lane's bin index gives the one-hot (Mosaic cannot reshape a
+(tile_n, d, B) block onto that axis). P is contracted against the
+weight column on the MXU at full f32 precision, accumulating into a
+resident (1, d*B) output row that the wrapper reshapes to (d, B).
+The stopping-rule scalars (W = sum|w|, V = sum w^2, T = sum wy)
 ride along in the same pass, so one sweep over the tile produces
 everything the stopping rule needs — the paper's "one scan" structure,
 VMEM-tiled.
@@ -26,10 +30,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import resolve_interpret
 
-def _edge_scan_kernel(xb_ref, wy_ref, w_ref, hist_ref, scal_ref, *, num_bins: int):
+
+#: cap on one grid step's lane-axis one-hot, in elements: two (tile_n,
+#: d*B) f32 temporaries of this size stay inside the 16 MiB scoped VMEM
+_ONEHOT_ELEMS = 1 << 20
+
+
+def _edge_scan_kernel(xb_ref, wy_ref, w_ref, expand_ref, lane_bin_ref, hist_ref, scal_ref):
     step = pl.program_id(0)
 
     @pl.when(step == 0)
@@ -37,21 +49,46 @@ def _edge_scan_kernel(xb_ref, wy_ref, w_ref, hist_ref, scal_ref, *, num_bins: in
         hist_ref[...] = jnp.zeros_like(hist_ref)
         scal_ref[...] = jnp.zeros_like(scal_ref)
 
-    xb = xb_ref[...]  # (tn, d) int32
     wy = wy_ref[...]  # (tn, 1) f32 (zero on padded rows)
     w = w_ref[...]  # (tn, 1) f32
+    # lane-expand the bins: xe[i, j*B + b] = xb[i, j]. A 0/1 matmul on
+    # small integers, exact in bf16 with f32 accumulation.
+    xb = xb_ref[...].astype(jnp.float32).astype(jnp.bfloat16)  # (tn, d)
+    xe = jnp.dot(xb, expand_ref[...], preferred_element_type=jnp.float32)  # (tn, d*B)
+    p = (xe == lane_bin_ref[...]).astype(jnp.float32)  # one-hot on the lane axis
+    # wy^T @ P on the MXU; full f32 contraction (the TPU default may
+    # round the f32 operand to bf16)
+    hist_ref[...] += jax.lax.dot_general(
+        wy,
+        p,
+        (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )  # (1, d*B)
 
-    tn, d = xb.shape
-    bins = jax.lax.broadcasted_iota(jnp.int32, (tn, d, num_bins), 2)
-    p = (xb[:, :, None] == bins).astype(jnp.float32)  # one-hot (tn, d, B)
-    p2 = p.reshape(tn, d * num_bins)
-    # (1, tn) @ (tn, d*B) on the MXU, f32 accumulate
-    g = jnp.dot(wy.reshape(1, tn), p2, preferred_element_type=jnp.float32)
-    hist_ref[...] += g.reshape(d, num_bins)
+    lane = jax.lax.broadcasted_iota(jnp.int32, scal_ref.shape, 1)
+    sum_abs = jnp.sum(jnp.abs(w), axis=0, keepdims=True)
+    sum_sq = jnp.sum(w * w, axis=0, keepdims=True)
+    sum_wy = jnp.sum(wy, axis=0, keepdims=True)
+    scal_ref[...] += jnp.where(
+        lane == 0, sum_abs, jnp.where(lane == 1, sum_sq, jnp.where(lane == 2, sum_wy, 0.0))
+    )
 
-    scal_ref[0, 0] += jnp.sum(jnp.abs(w))
-    scal_ref[0, 1] += jnp.sum(w * w)
-    scal_ref[0, 2] += jnp.sum(wy)
+
+def lane_expansion(d: int, width: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``(E, lane)`` for a lane-axis one-hot of ``d`` features x ``width``
+    values: ``E`` (d, d*width) bf16 copies feature j onto lanes
+    ``j*width .. j*width+width-1``, and ``lane`` (1, d*width) f32 holds
+    each lane's value index."""
+    expand = np.repeat(np.eye(d, dtype=np.float32), width, axis=1)
+    lane = np.tile(np.arange(width, dtype=np.float32), d)[None, :]
+    return jnp.asarray(expand, jnp.bfloat16), jnp.asarray(lane)
+
+
+def onehot_tile(tile_n: int, width: int) -> int:
+    """``tile_n``, shrunk to a multiple of 8 where its (tile, width)
+    one-hot would exceed the per-step budget."""
+    return max(8, min(tile_n, _ONEHOT_ELEMS // width // 8 * 8))
 
 
 @functools.partial(
@@ -64,7 +101,7 @@ def edge_scan(
     *,
     num_bins: int,
     tile_n: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Accumulate the (feature, bin) wy-histogram + stopping-rule scalars.
 
@@ -73,14 +110,16 @@ def edge_scan(
         wy: (n,) f32 signed weights ``w_i * y_i``.
         w:  (n,) f32 weights.
         num_bins: B (static).
-        tile_n: rows per grid step (VMEM tile height).
-        interpret: run the kernel body in interpret mode (CPU container);
-            on a real TPU pass False.
+        tile_n: rows per grid step (VMEM tile height); capped so the
+            (tile_n, d*B) one-hot fits VMEM.
+        interpret: ``None`` follows the platform (compiled on a TPU,
+            interpreted elsewhere).
 
     Returns:
         (hist (d, B) f32, W (), V (), T ()).
     """
     n, d = xb.shape
+    tile_n = onehot_tile(tile_n, d * num_bins)
     n_pad = -n % tile_n
     if n_pad:
         xb = jnp.pad(xb, ((0, n_pad), (0, 0)))
@@ -89,23 +128,27 @@ def edge_scan(
     steps = xb.shape[0] // tile_n
     wy2 = wy.reshape(-1, 1)
     w2 = w.reshape(-1, 1)
+    expand, lane_bin = lane_expansion(d, num_bins)
+    width = d * num_bins
 
     hist, scal = pl.pallas_call(
-        functools.partial(_edge_scan_kernel, num_bins=num_bins),
+        _edge_scan_kernel,
         grid=(steps,),
         in_specs=[
             pl.BlockSpec((tile_n, d), lambda i: (i, 0)),
             pl.BlockSpec((tile_n, 1), lambda i: (i, 0)),
             pl.BlockSpec((tile_n, 1), lambda i: (i, 0)),
+            pl.BlockSpec((d, width), lambda i: (0, 0)),
+            pl.BlockSpec((1, width), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((d, num_bins), lambda i: (0, 0)),
+            pl.BlockSpec((1, width), lambda i: (0, 0)),
             pl.BlockSpec((1, 4), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((d, num_bins), jnp.float32),
+            jax.ShapeDtypeStruct((1, width), jnp.float32),
             jax.ShapeDtypeStruct((1, 4), jnp.float32),
         ],
-        interpret=interpret,
-    )(xb, wy2, w2)
-    return hist, scal[0, 0], scal[0, 1], scal[0, 2]
+        interpret=resolve_interpret(interpret),
+    )(xb, wy2, w2, expand, lane_bin)
+    return hist.reshape(d, num_bins), scal[0, 0], scal[0, 1], scal[0, 2]

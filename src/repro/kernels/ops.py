@@ -1,10 +1,10 @@
 """Public jit'd entry points for the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode; on a real
-TPU deployment ``interpret`` resolves to False and the same call sites
-get the compiled Mosaic kernels. Tile sizes default to MXU-aligned
-values (the second-minor dim of every matmul operand is a multiple of
-128 when d*B is — configs pick d and B accordingly; see DESIGN.md §6).
+``interpret=None`` (the default everywhere) follows the platform: on a
+TPU the call sites get the compiled Mosaic kernels, on any other
+backend the Pallas interpreter (see :mod:`repro.kernels.platform`).
+Tile sizes default to MXU-aligned values; the one-hot kernels shrink
+``tile_n`` where a (tile_n, d*B) one-hot would not fit VMEM.
 """
 
 from __future__ import annotations
@@ -19,10 +19,6 @@ from repro.kernels.round_step import queue_ingest as _queue_ingest, round_step a
 from repro.kernels.weight_update import scatter_model_slice, weight_update as _weight_update
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def edge_scan(
     xb: jnp.ndarray,
     wy: jnp.ndarray,
@@ -33,8 +29,6 @@ def edge_scan(
     interpret: bool | None = None,
 ):
     """(hist (d,B), W, V, T) — see :mod:`repro.kernels.edge_scan`."""
-    if interpret is None:
-        interpret = _default_interpret()
     return _edge_scan(xb, wy, w, num_bins=num_bins, tile_n=tile_n, interpret=interpret)
 
 
@@ -61,8 +55,6 @@ def edge_scan_batched(
 
     Returns (hist (W, d, B), W_ (W,), V (W,), T (W,)).
     """
-    if interpret is None:
-        interpret = _default_interpret()
     fn = functools.partial(_edge_scan, num_bins=num_bins, tile_n=tile_n, interpret=interpret)
     return jax.vmap(fn)(xb, wy, w)
 
@@ -88,22 +80,19 @@ def edge_scan_sharded(
     W to W_local. ``tests/test_kernels.py`` pins the output against the
     unsharded batched path when multiple devices are visible.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    if interpret is None:
-        interpret = _default_interpret()
     if xb.shape[0] % mesh.shape["workers"]:
         raise ValueError(
             f"worker axis {xb.shape[0]} must divide over {mesh.shape['workers']} devices"
         )
     fn = functools.partial(_edge_scan, num_bins=num_bins, tile_n=tile_n, interpret=interpret)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda a, b, c: jax.vmap(fn)(a, b, c),
         mesh=mesh,
         in_specs=(P("workers"), P("workers"), P("workers")),
         out_specs=(P("workers"), P("workers"), P("workers"), P("workers")),
-        check_rep=False,
+        check_vma=False,
     )
     return sharded(xb, wy, w)
 
@@ -121,8 +110,6 @@ def weight_update(
     interpret: bool | None = None,
 ):
     """(margin_new, w) — see :mod:`repro.kernels.weight_update`."""
-    if interpret is None:
-        interpret = _default_interpret()
     return _weight_update(
         xb, y, margin_l, margin_s, a, c, num_bins=num_bins, tile_n=tile_n, interpret=interpret
     )
@@ -149,8 +136,6 @@ def round_deliver(
     ``alive`` in, bool ``take``/``active`` out); the int32 conversion
     the TPU kernel needs at its boundary happens here.
     """
-    if interpret is None:
-        interpret = _default_interpret()
     out = _round_step(
         q_cert,
         q_due,
@@ -197,8 +182,6 @@ def queue_ingest(
     operands numeric — no boolean boundary conversion needed); returns
     ``(q_cert', q_due', q_src', q_slot')``.
     """
-    if interpret is None:
-        interpret = _default_interpret()
     return _queue_ingest(
         q_cert,
         q_due,

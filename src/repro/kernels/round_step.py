@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import resolve_interpret
+
 _I32_MAX = 2**31 - 1
 
 
@@ -163,7 +165,7 @@ def queue_ingest(
     c_slot: jnp.ndarray,
     *,
     tile_w: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Sparse-control candidate-list ingest: merge the (W, m) candidate
     block into the (W, C) pending queues, keeping the lexicographically
@@ -176,7 +178,8 @@ def queue_ingest(
         c_cert/c_due/c_src/c_slot: (W, m) candidate block — +inf cert /
             due -1 marks an invalid (padded or self/OOB) candidate.
         tile_w: destination rows per grid step.
-        interpret: interpret mode (CPU container); False on a real TPU.
+        interpret: ``None`` follows the platform (compiled on a TPU,
+            interpreted elsewhere).
 
     Returns ``(q_cert', q_due', q_src', q_slot')``, each (W, C).
     """
@@ -208,7 +211,7 @@ def queue_ingest(
             jax.ShapeDtypeStruct((w + w_pad, cap), jnp.int32),
             jax.ShapeDtypeStruct((w + w_pad, cap), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q_cert, q_due, q_src, q_slot, c_cert, c_due, c_src, c_slot)
     return tuple(a[:w] for a in out)
 
@@ -227,7 +230,7 @@ def round_step(
     *,
     eps: float,
     tile_w: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Fused sparse delivery + accept + credit; see the module docstring.
 
@@ -240,7 +243,8 @@ def round_step(
         r: () i32 current round.
         eps: static protocol acceptance gap.
         tile_w: destination rows per grid step.
-        interpret: interpret mode (CPU container); False on a real TPU.
+        interpret: ``None`` follows the platform (compiled on a TPU,
+            interpreted elsewhere).
 
     Returns ``(q_cert', best_cert, best_src, best_slot, take, n_arr,
     credit', active)`` — (W, C) and seven (W,) arrays; ``take`` and
@@ -296,7 +300,7 @@ def round_step(
             jax.ShapeDtypeStruct((w + w_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((w + w_pad, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(
         q_cert,
         q_due,

@@ -13,7 +13,8 @@ then per example the margin delta is
     H_hi(x) - H_lo(x)  =  2 * (P[i, :] @ A) - c,
     P[i, (j, t)]       =  [xb[i, j] > t]
 
-one (tile_n, d*(B-1)) x (d*(B-1), 1) matmul per VMEM tile on the MXU,
+one masked lane sum of P against A (flattened to a (1, d*(B-1)) row)
+per VMEM tile, with P built on the lane axis as in ``edge_scan``,
 followed by the elementwise weight epilogue on the VPU:
 
     margin' = margin_l + delta
@@ -32,20 +33,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.boosting.stumps import StumpModel
+from repro.kernels.edge_scan import lane_expansion, onehot_tile
+from repro.kernels.platform import resolve_interpret
 
 _CLIP = 30.0
 
 
 def _weight_update_kernel(
-    xb_ref, y_ref, ml_ref, ms_ref, a_ref, c_ref, mout_ref, wout_ref, *, num_cuts: int
+    xb_ref, y_ref, ml_ref, ms_ref, expand_ref, lane_cut_ref, a_ref, c_ref, mout_ref, wout_ref
 ):
-    xb = xb_ref[...]  # (tn, d) int32
-    tn, d = xb.shape
-    cuts = jax.lax.broadcasted_iota(jnp.int32, (tn, d, num_cuts), 2)
-    p = (xb[:, :, None] > cuts).astype(jnp.float32)  # (tn, d, B-1)
-    p2 = p.reshape(tn, d * num_cuts)
-    a = a_ref[...].reshape(d * num_cuts, 1)
-    delta = 2.0 * jnp.dot(p2, a, preferred_element_type=jnp.float32) - c_ref[0, 0]
+    # lane-expand the bins onto (tn, d*(B-1)) exactly as edge_scan does
+    xb = xb_ref[...].astype(jnp.float32).astype(jnp.bfloat16)  # (tn, d)
+    xe = jnp.dot(xb, expand_ref[...], preferred_element_type=jnp.float32)
+    p = xe > lane_cut_ref[...]  # P[i, (j, t)] = [xb[i, j] > t]
+    # P @ A as a masked lane sum on the VPU: exact f32, no N=1 matmul
+    pa = jnp.sum(jnp.where(p, a_ref[...], 0.0), axis=1, keepdims=True)  # (tn, 1)
+    delta = 2.0 * pa - c_ref[...]
     m_new = ml_ref[...] + delta  # (tn, 1)
     logw = -y_ref[...] * (m_new - ms_ref[...])
     mout_ref[...] = m_new
@@ -63,7 +66,7 @@ def weight_update(
     *,
     num_bins: int,
     tile_n: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused incremental margin + weight refresh over a block of examples.
 
@@ -75,12 +78,16 @@ def weight_update(
         a: (d, B-1) scattered stump-slice coefficients (see module doc).
         c: () scalar sum of the slice's alpha*sign.
         num_bins: B (static).
+        interpret: ``None`` follows the platform (compiled on a TPU,
+            interpreted elsewhere).
 
     Returns:
         (margin_new (n,), w (n,)) with ``w = exp(-y (margin_new - margin_s))``.
     """
     n, d = xb.shape
     num_cuts = num_bins - 1
+    width = d * num_cuts
+    tile_n = onehot_tile(tile_n, width)
     n_pad = -n % tile_n
     if n_pad:
         xb = jnp.pad(xb, ((0, n_pad), (0, 0)))
@@ -89,28 +96,38 @@ def weight_update(
         margin_s = jnp.pad(margin_s, (0, n_pad))
     steps = xb.shape[0] // tile_n
     col = lambda v: v.reshape(-1, 1)
+    expand, lane_cut = lane_expansion(d, num_cuts)
+    row_spec = pl.BlockSpec((tile_n, 1), lambda i: (i, 0))
 
     m_new, w = pl.pallas_call(
-        functools.partial(_weight_update_kernel, num_cuts=num_cuts),
+        _weight_update_kernel,
         grid=(steps,),
         in_specs=[
             pl.BlockSpec((tile_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((tile_n, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile_n, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile_n, 1), lambda i: (i, 0)),
-            pl.BlockSpec((d, num_cuts), lambda i: (0, 0)),
+            row_spec,
+            row_spec,
+            row_spec,
+            pl.BlockSpec((d, width), lambda i: (0, 0)),
+            pl.BlockSpec((1, width), lambda i: (0, 0)),
+            pl.BlockSpec((1, width), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((tile_n, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tile_n, 1), lambda i: (i, 0)),
-        ],
+        out_specs=[row_spec, row_spec],
         out_shape=[
             jax.ShapeDtypeStruct((xb.shape[0], 1), jnp.float32),
             jax.ShapeDtypeStruct((xb.shape[0], 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(xb, col(y), col(margin_l), col(margin_s), a, jnp.asarray(c, jnp.float32).reshape(1, 1))
+        interpret=resolve_interpret(interpret),
+    )(
+        xb,
+        col(y),
+        col(margin_l),
+        col(margin_s),
+        expand,
+        lane_cut,
+        a.astype(jnp.float32).reshape(1, width),
+        jnp.asarray(c, jnp.float32).reshape(1, 1),
+    )
     return m_new[:n, 0], w[:n, 0]
 
 

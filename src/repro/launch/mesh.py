@@ -11,17 +11,25 @@ module never touches jax device state — the dry-run must set
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes: the engines and steps partition
+    through ``shard_map`` and sharding annotations, not through
+    sharding-in-types (which ``make_mesh`` defaults to on JAX >= 0.8)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs of the same step functions."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def make_worker_mesh(num_devices: int | None = None, pods: int = 1):
@@ -52,10 +60,10 @@ def make_worker_mesh(num_devices: int | None = None, pods: int = 1):
     if pods < 1:
         raise ValueError(f"pods={pods} must be >= 1")
     if pods == 1:
-        return jax.make_mesh((num_devices,), ("workers",))
+        return _mesh((num_devices,), ("workers",))
     if num_devices % pods:
         raise ValueError(f"num_devices={num_devices} must divide into {pods} pods")
-    return jax.make_mesh((pods, num_devices // pods), ("pod", "workers"))
+    return _mesh((pods, num_devices // pods), ("pod", "workers"))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

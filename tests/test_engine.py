@@ -11,6 +11,8 @@ Equivalence strategy (DESIGN: the event sim is the fidelity-1 oracle):
     including the resample path and the Pallas kernel scan path.
 """
 
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,7 +22,7 @@ from repro.boosting import BatchedSparrowWorker, SparrowConfig, SparrowWorker
 from repro.boosting.batched_sparrow import common_prefix_len
 from repro.boosting.scanner import ScannerConfig
 from repro.boosting.sparrow import feature_ownership_masks
-from repro.core.engine import EngineConfig, TMSNEngine, quantize_latency
+from repro.core.engine import EngineConfig, TMSNEngine, make_engine, quantize_latency
 from repro.core.simulator import SimulatorConfig, TMSNSimulator, WorkerSpec
 from repro.data.splice import SpliceConfig, make_splice_like, train_test_split
 
@@ -441,6 +443,23 @@ class TestBatchedSparrow:
         np.testing.assert_allclose(
             np.asarray(states[True].cert), np.asarray(states[False].cert), rtol=1e-4
         )
+
+    def test_disk_data_is_a_chunk_argument(self, small_data):
+        """The engine passes the disk dataset into its jitted chunk as an
+        argument: closed over, jit would embed it as a constant (JAX
+        warns above the threshold; the disk set here is ~1.2 MB)."""
+        xtr, ytr, _, _ = small_data
+        worker = BatchedSparrowWorker(xtr, ytr, _cfg(2, sample_size=256, capacity=16))
+        limit = jax.config.jax_captured_constants_warn_bytes
+        jax.config.update("jax_captured_constants_warn_bytes", 200_000)
+        try:
+            with warnings.catch_warnings():
+                warnings.filterwarnings("error", message=".*constants were captured.*")
+                make_engine(
+                    worker, EngineConfig(n_workers=2, max_rounds=4, fault_spec="")
+                ).run()
+        finally:
+            jax.config.update("jax_captured_constants_warn_bytes", limit)
 
     def test_adopt_batch_matches_unbatched(self, small_data):
         """Adoption = prefix-sharing margin transfer; batched vs oracle."""
