@@ -55,9 +55,7 @@ under a hard address-space cap (RLIMIT_AS): the dense buffer alone
 (512 x 4096 x 64 f32 per shard, plus its shift copy) exceeds the cap,
 so dense must die while sparse completes (dense's in-flight state is a
 single 4 GiB allocation before its shift copy; sparse peaks well under
-the cap) — the bench fails loudly if dense unexpectedly fits. A
-roofline accounting of the fused kernel
-(launch/hlo_analysis.round_step_roofline) closes the section.
+the cap) — the bench fails loudly if dense unexpectedly fits.
 
 The *control-plane* section sweeps W ∈ {4096, 10240} (toy worker,
 gated gossip, capacity 64, uniform delay, the same 9 GiB RLIMIT_AS cap)
@@ -849,23 +847,6 @@ def run(quick: bool = False) -> list[str]:
         f"{pre}.certs_identical_to_dense,"
         f"{int(chet_s['certs_digest'] == chet_d['certs_digest'])},het_delay_approx"
     )
-
-    # roofline accounting of the fused delivery kernel at the sweep sizes
-    from repro.launch.hlo_analysis import round_step_roofline
-
-    for rw in (1024, w4):
-        rf = round_step_roofline(rw, cap)
-        out[f"round_step_roofline_w{rw}_c{cap}"] = rf
-        pre = f"scaling.round_step_w{rw}_c{cap}"
-        lines.append(
-            f"{pre}.arith_intensity,{rf['arith_intensity_flops_per_byte']:.3f},"
-            f"ridge_{rf['ridge_point_flops_per_byte']:.0f}_{rf['bound']}_bound"
-        )
-        lines.append(f"{pre}.projected_us,{rf['projected_us']:.2f},tpu_v5e_hbm_floor")
-        lines.append(
-            f"{pre}.fusion_overhead_x,{rf['fusion_overhead_x']:.2f},"
-            f"ref_hlo_bytes_over_operand_floor"
-        )
 
     _write_results("scaling.json", out)
     return lines

@@ -59,11 +59,8 @@ GUARDED = [
     ("scaling.sharded_w*.gossip_bytes_per_round", 0.20),
     ("scaling.dispatch_w*.wall_ms_per_round", 0.20),
     # sparse pending-queue sweeps (uniform, het-delay, and the capped
-    # W=4096 run dense cannot complete) plus the fused round kernel's
-    # projected HBM floor (deterministic — drift means the kernel's
-    # operand footprint changed)
+    # W=4096 run dense cannot complete)
     ("scaling.sparse_w*.wall_ms_per_round", 0.20),
-    ("scaling.round_step_w*.projected_us", 0.20),
     # control-plane sweep (dense certs/flags vs top-k triples): the
     # byte figures are exact formulas, so the tight guard catches any
     # control-accounting regression; wall clock gets the usual headroom

@@ -268,7 +268,7 @@ def first_parting_round(engines, rounds: int, chunk: int) -> int | None:
     done = 0
     while done < rounds:
         k = min(chunk, rounds - done)
-        outs = [e._chunk_fn(k)(s) for e, s in zip(engines, states)]
+        outs = [e._chunk_fn(k, s)(s) for e, s in zip(engines, states)]
         states = [s for s, _ in outs]
         certs = [np.asarray(info.certs) for _, info in outs]
         differ = np.any(certs[0] != certs[1], axis=1)

@@ -106,6 +106,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.protocol import accepts, improves
 from repro.core.result import SimResult, TrafficCounters
 from repro.core.worker import (
@@ -900,11 +901,27 @@ class RoundInfo(NamedTuple):
     alive: jnp.ndarray  # (W,)
 
 
+def _to_host(x: Any) -> np.ndarray:
+    """Blocking device-to-host read inside :meth:`TMSNEngine.run`,
+    counted as the run's ``host_fetches``."""
+    telemetry.count("host_fetches")
+    return np.asarray(x)
+
+
 def _tree_stack_rows(tree: Any, depth: int) -> Any:
     """Tile a stacked (W, ...) pytree into a (D, W, ...) ring."""
     return jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[None], (depth,) + a.shape).copy(), tree
     )
+
+
+def _compile(jitted, *args):
+    """Compile ``jitted`` for ``args`` ahead of time and register the
+    scope map of the very program that runs (the instruction names of a
+    profiler trace are this executable's)."""
+    compiled = jitted.lower(*args).compile()
+    telemetry.register_program(compiled.as_text())
+    return compiled
 
 
 class TMSNEngine:
@@ -1127,17 +1144,19 @@ class TMSNEngine:
                 # discarding it. `done` derives from an all-shard
                 # reduction, so every device takes the same branch and
                 # the collectives inside stay uniform.
-                new_state, info = jax.lax.cond(done, frozen, step, state)
+                with jax.named_scope(telemetry.FREEZE):
+                    new_state, info = jax.lax.cond(done, frozen, step, state)
                 done = done | any_reduce(info.alive & (info.certs <= target))
             return (new_state, done), info
 
         return body
 
-    def _build_chunk(self, length: int):
-        """Jitted ``state -> (state, RoundInfo stacked over length)``;
-        the sharded engine overrides this to run the scan inside
-        ``shard_map``. The worker's shared read-only data enters as an
-        argument, so it is never baked into the program as a constant."""
+    def _build_chunk(self, length: int, state: EngineState):
+        """Compiled ``state -> (state, RoundInfo stacked over length)``
+        for states shaped like ``state``; the sharded engine overrides
+        this to run the scan inside ``shard_map``. The worker's shared
+        read-only data enters as an argument, so it is never baked into
+        the program as a constant."""
         body = self._chunk_body(self._round_step, jnp.any)
 
         def chunk(state: EngineState, shared: Any):
@@ -1147,13 +1166,16 @@ class TMSNEngine:
                 )
             return state, infos
 
-        step = jax.jit(chunk)
+        step = _compile(jax.jit(chunk), state, self._shared)
         return lambda state: step(state, self._shared)
 
-    def _chunk_fn(self, length: int):
+    def _chunk_fn(self, length: int, state: EngineState):
+        """The chunk program of ``length`` rounds, compiled ahead of time
+        on a miss (counted as the run's ``chunk_compiles``)."""
         fn = self._chunks.get(length)
         if fn is None:
-            fn = self._chunks[length] = self._build_chunk(length)
+            telemetry.count("chunk_compiles")
+            fn = self._chunks[length] = self._build_chunk(length, state)
         return fn
 
     # ------------------------------------------------------------------
@@ -1271,96 +1293,136 @@ class TMSNEngine:
         certs0 = state.certs
 
         # --- 1.+2.(+3. credit) deliver arrivals due this round ------------
-        if self._capacity:
-            # sparse path: delivery argmin + accept gate + credit are
-            # one fused kernel call; clearing the delivered certs
-            # replaces the dense buffer shift (dues are absolute)
-            (
-                inflight,
-                best_cert,
-                best_src,
-                sent_slot,
-                take,
-                n_arrivals,
-                credit,
-                active,
-            ) = self._deliver_sparse(
-                state.inflight, certs0, alive, credit_in, self._speed_norm, r
-            )
-        else:
-            arr = state.inflight[:, :, 0]  # (dst, src) certs
-            arr_live = jnp.where(alive[:, None], arr, jnp.inf)
-            best_src = jnp.argmin(arr_live, axis=1)  # (W,)
-            best_cert = arr_live[dst_idx, best_src]
-            take = accepts(certs0, best_cert, cfg.eps) & jnp.isfinite(best_cert)
-            n_arrivals = jnp.sum(jnp.isfinite(arr), dtype=jnp.int32)
-            sent_slot = (r - self._delay[best_src, dst_idx]) % depth
-            # shift the in-flight buffer
-            inflight = jnp.concatenate(
-                [state.inflight[:, :, 1:], jnp.full((w, w, 1), jnp.inf, jnp.float32)],
-                axis=2,
-            )
-            credit = credit_in + self._speed_norm
-            active = alive & (credit >= 1.0 - 1e-6)
-            credit = jnp.where(active, credit - 1.0, credit)
+        with jax.named_scope(telemetry.DELIVER):
+            if self._capacity:
+                # sparse path: delivery argmin + accept gate + credit are
+                # one fused kernel call; clearing the delivered certs
+                # replaces the dense buffer shift (dues are absolute)
+                (
+                    inflight,
+                    best_cert,
+                    best_src,
+                    sent_slot,
+                    take,
+                    n_arrivals,
+                    credit,
+                    active,
+                ) = self._deliver_sparse(
+                    state.inflight, certs0, alive, credit_in, self._speed_norm, r
+                )
+            else:
+                arr = state.inflight[:, :, 0]  # (dst, src) certs
+                arr_live = jnp.where(alive[:, None], arr, jnp.inf)
+                best_src = jnp.argmin(arr_live, axis=1)  # (W,)
+                best_cert = arr_live[dst_idx, best_src]
+                take = accepts(certs0, best_cert, cfg.eps) & jnp.isfinite(best_cert)
+                n_arrivals = jnp.sum(jnp.isfinite(arr), dtype=jnp.int32)
+                sent_slot = (r - self._delay[best_src, dst_idx]) % depth
+                # shift the in-flight buffer
+                inflight = jnp.concatenate(
+                    [state.inflight[:, :, 1:], jnp.full((w, w, 1), jnp.inf, jnp.float32)],
+                    axis=2,
+                )
+                credit = credit_in + self._speed_norm
+                active = alive & (credit >= 1.0 - 1e-6)
+                credit = jnp.where(active, credit - 1.0, credit)
         n_taken = jnp.sum(take, dtype=jnp.int32)
 
-        in_models = jax.tree_util.tree_map(
-            lambda a: a[sent_slot, best_src], state.ring
-        )
+        with jax.named_scope(telemetry.ADOPT):
+            in_models = jax.tree_util.tree_map(
+                lambda a: a[sent_slot, best_src], state.ring
+            )
 
-        def _adopt(operand):
-            wstate, models, c, t = operand
-            return self.worker.adopt_batch(wstate, models, c, t)
+            def _adopt(operand):
+                wstate, models, c, t = operand
+                return self.worker.adopt_batch(wstate, models, c, t)
 
-        wstate, adopt_cost = jax.lax.cond(
-            jnp.any(take),
-            _adopt,
-            lambda operand: (operand[0], jnp.zeros((w,), jnp.float32)),
-            (state.worker, in_models, best_cert, take),
-        )
+            wstate, adopt_cost = jax.lax.cond(
+                jnp.any(take),
+                _adopt,
+                lambda operand: (operand[0], jnp.zeros((w,), jnp.float32)),
+                (state.worker, in_models, best_cert, take),
+            )
 
         # --- 3. one segment per live, credit-covered worker ---------------
         # (workers without the optional resample hooks skip this branch
         # statically — see repro.core.worker.has_resample_hooks)
-        if self._has_resample:
-            need = self.worker.needs_resample(wstate) & active
-            wstate, resample_cost = jax.lax.cond(
-                jnp.any(need),
-                lambda op: self.worker.resample_round(op[0], op[1]),
-                lambda op: (op[0], jnp.zeros((w,), jnp.float32)),
-                (wstate, need),
-            )
-            scan_mask = active & ~need
-        else:
-            resample_cost = jnp.zeros((w,), jnp.float32)
-            scan_mask = active
-        certs_pre = self.worker.certificates(wstate)
-        wstate, scan_cost, fired = self.worker.scan_round(wstate, scan_mask)
-        certs = self.worker.certificates(wstate)
+        with jax.named_scope(telemetry.RESAMPLE):
+            if self._has_resample:
+                need = self.worker.needs_resample(wstate) & active
+                wstate, resample_cost = jax.lax.cond(
+                    jnp.any(need),
+                    lambda op: self.worker.resample_round(op[0], op[1]),
+                    lambda op: (op[0], jnp.zeros((w,), jnp.float32)),
+                    (wstate, need),
+                )
+                scan_mask = active & ~need
+            else:
+                resample_cost = jnp.zeros((w,), jnp.float32)
+                scan_mask = active
+        with jax.named_scope(telemetry.SCAN):
+            certs_pre = self.worker.certificates(wstate)
+            wstate, scan_cost, fired = self.worker.scan_round(wstate, scan_mask)
+            certs = self.worker.certificates(wstate)
 
         cost = adopt_cost + resample_cost + scan_cost
         clock = state.clock + cost / jnp.maximum(self._speed, 1e-12)
 
         # --- 4. broadcast strict improvements -----------------------------
         # (eps gates acceptance only — see the note in simulator.run)
-        improved = fired & improves(certs_pre, certs, 0.0) & scan_mask
-        n_evicted = jnp.zeros((), jnp.int32)
-        occ_pre_max = jnp.zeros((), jnp.int32)
-        n_dropped = jnp.zeros((), jnp.int32)
-        n_rejected = jnp.zeros((), jnp.int32)
-        if self._control_sparse:
-            # sparse control plane: only the top-k improvers are offered
-            # (single-device analogue of the (n_dev, k) all_gather). The
-            # suppressed runner-ups could never have been accepted under
-            # uniform delay — every receiver's best arrival is the
-            # global min, except the min's own sender, whose local cert
-            # is already at least as good as any runner-up.
-            kc = min(int(cfg.gossip_top_k), w)
-            rows, validk = self._top_k_candidates(improved, certs, kc)
-            cand_ids = jnp.where(validk, rows.astype(jnp.int32), w)
-            cand_certs = jnp.where(validk, certs[rows], jnp.inf)
-            if self._capacity:
+        with jax.named_scope(telemetry.BROADCAST):
+            improved = fired & improves(certs_pre, certs, 0.0) & scan_mask
+            n_evicted = jnp.zeros((), jnp.int32)
+            occ_pre_max = jnp.zeros((), jnp.int32)
+            n_dropped = jnp.zeros((), jnp.int32)
+            n_rejected = jnp.zeros((), jnp.int32)
+            if self._control_sparse:
+                # sparse control plane: only the top-k improvers are offered
+                # (single-device analogue of the (n_dev, k) all_gather). The
+                # suppressed runner-ups could never have been accepted under
+                # uniform delay — every receiver's best arrival is the
+                # global min, except the min's own sender, whose local cert
+                # is already at least as good as any runner-up.
+                kc = min(int(cfg.gossip_top_k), w)
+                rows, validk = self._top_k_candidates(improved, certs, kc)
+                cand_ids = jnp.where(validk, rows.astype(jnp.int32), w)
+                cand_certs = jnp.where(validk, certs[rows], jnp.inf)
+                if self._capacity:
+                    (
+                        inflight,
+                        n_pushed,
+                        n_evicted,
+                        occ_pre_max,
+                        n_dropped,
+                        n_rejected,
+                    ) = _queue_push_candidates(
+                        inflight,
+                        cand_certs,
+                        cand_ids,
+                        alive,
+                        dst_idx.astype(jnp.int32),
+                        self._delay.T,  # (dst, src) rows
+                        r,
+                        depth,
+                        cfg.round_step_impl,
+                        dst_cert=certs,
+                        fault=self._fault,
+                        pod_of=self._pod_of,
+                    )
+                else:
+                    inflight, n_pushed, n_dropped, n_rejected = _dense_push_candidates(
+                        inflight,
+                        cand_certs,
+                        cand_ids,
+                        alive,
+                        dst_idx.astype(jnp.int32),
+                        self._delay.T,
+                        r=r,
+                        dst_cert=certs,
+                        fault=self._fault,
+                        pod_of=self._pod_of,
+                    )
+            elif self._capacity:
                 (
                     inflight,
                     n_pushed,
@@ -1368,109 +1430,74 @@ class TMSNEngine:
                     occ_pre_max,
                     n_dropped,
                     n_rejected,
-                ) = _queue_push_candidates(
+                ) = _queue_push(
                     inflight,
-                    cand_certs,
-                    cand_ids,
+                    jnp.where(improved, certs, jnp.inf),
                     alive,
-                    dst_idx.astype(jnp.int32),
+                    dst_idx,
                     self._delay.T,  # (dst, src) rows
                     r,
                     depth,
-                    cfg.round_step_impl,
                     dst_cert=certs,
                     fault=self._fault,
                     pod_of=self._pod_of,
                 )
+            elif self._fault is None:
+                d_idx = jnp.arange(depth)[None, None, :]
+                # push_mask[dst, src, d] — delay is indexed [src, dst]
+                push_mask = (
+                    improved[None, :, None]
+                    & alive[:, None, None]
+                    & (dst_idx[:, None] != dst_idx[None, :])[:, :, None]
+                    & (d_idx == (self._delay.T[:, :, None] - 1))
+                )
+                inflight = jnp.where(push_mask, certs[None, :, None], inflight)
+                n_pushed = jnp.sum(push_mask, dtype=jnp.int32)
             else:
-                inflight, n_pushed, n_dropped, n_rejected = _dense_push_candidates(
-                    inflight,
-                    cand_certs,
-                    cand_ids,
-                    alive,
+                # faulted dense push: same mask, but carried as a per-edge
+                # (dst, src) certificate matrix so _inject_faults can drop /
+                # corrupt / soundness-reject individual edges
+                push2 = (
+                    improved[None, :]
+                    & alive[:, None]
+                    & (dst_idx[:, None] != dst_idx[None, :])
+                )
+                cert_mat = jnp.where(push2, certs[None, :], jnp.inf)
+                src_mat = jnp.broadcast_to(
+                    dst_idx[None, :].astype(jnp.int32), (w, w)
+                )
+                cert_mat, _, _, n_dropped, n_rejected = _inject_faults(
+                    self._fault,
+                    self._pod_of,
+                    r,
                     dst_idx.astype(jnp.int32),
-                    self._delay.T,
-                    r=r,
-                    dst_cert=certs,
-                    fault=self._fault,
-                    pod_of=self._pod_of,
+                    src_mat,
+                    cert_mat,
+                    None,
+                    certs,
+                    depth,
                 )
-        elif self._capacity:
-            (
-                inflight,
-                n_pushed,
-                n_evicted,
-                occ_pre_max,
-                n_dropped,
-                n_rejected,
-            ) = _queue_push(
-                inflight,
-                jnp.where(improved, certs, jnp.inf),
-                alive,
-                dst_idx,
-                self._delay.T,  # (dst, src) rows
-                r,
-                depth,
-                dst_cert=certs,
-                fault=self._fault,
-                pod_of=self._pod_of,
-            )
-        elif self._fault is None:
-            d_idx = jnp.arange(depth)[None, None, :]
-            # push_mask[dst, src, d] — delay is indexed [src, dst]
-            push_mask = (
-                improved[None, :, None]
-                & alive[:, None, None]
-                & (dst_idx[:, None] != dst_idx[None, :])[:, :, None]
-                & (d_idx == (self._delay.T[:, :, None] - 1))
-            )
-            inflight = jnp.where(push_mask, certs[None, :, None], inflight)
-            n_pushed = jnp.sum(push_mask, dtype=jnp.int32)
-        else:
-            # faulted dense push: same mask, but carried as a per-edge
-            # (dst, src) certificate matrix so _inject_faults can drop /
-            # corrupt / soundness-reject individual edges
-            push2 = (
-                improved[None, :]
-                & alive[:, None]
-                & (dst_idx[:, None] != dst_idx[None, :])
-            )
-            cert_mat = jnp.where(push2, certs[None, :], jnp.inf)
-            src_mat = jnp.broadcast_to(
-                dst_idx[None, :].astype(jnp.int32), (w, w)
-            )
-            cert_mat, _, _, n_dropped, n_rejected = _inject_faults(
-                self._fault,
-                self._pod_of,
-                r,
-                dst_idx.astype(jnp.int32),
-                src_mat,
-                cert_mat,
-                None,
-                certs,
-                depth,
-            )
-            d_idx = jnp.arange(depth)[None, None, :]
-            push_mask = jnp.isfinite(cert_mat)[:, :, None] & (
-                d_idx == (self._delay.T[:, :, None] - 1)
-            )
-            inflight = jnp.where(push_mask, cert_mat[:, :, None], inflight)
-            n_pushed = jnp.sum(push2, dtype=jnp.int32)  # logical sends
+                d_idx = jnp.arange(depth)[None, None, :]
+                push_mask = jnp.isfinite(cert_mat)[:, :, None] & (
+                    d_idx == (self._delay.T[:, :, None] - 1)
+                )
+                inflight = jnp.where(push_mask, cert_mat[:, :, None], inflight)
+                n_pushed = jnp.sum(push2, dtype=jnp.int32)  # logical sends
 
-        # --- 5. snapshot the models into the ring -------------------------
-        # gated to broadcasters: ring[slot, src] is only ever read for a
-        # message src pushed at that slot's round, so non-improved
-        # workers keep their (dead) old entry instead of paying a write
-        models = self.worker.export_models(wstate)
-        ring = jax.tree_util.tree_map(
-            lambda buf, m: buf.at[r % depth].set(
-                jnp.where(
-                    improved.reshape((-1,) + (1,) * (m.ndim - 1)), m, buf[r % depth]
-                )
-            ),
-            state.ring,
-            models,
-        )
+            # --- 5. snapshot the models into the ring -------------------------
+            # gated to broadcasters: ring[slot, src] is only ever read for a
+            # message src pushed at that slot's round, so non-improved
+            # workers keep their (dead) old entry instead of paying a write
+            models = self.worker.export_models(wstate)
+            ring = jax.tree_util.tree_map(
+                lambda buf, m: buf.at[r % depth].set(
+                    jnp.where(
+                        improved.reshape((-1,) + (1,) * (m.ndim - 1)), m, buf[r % depth]
+                    )
+                ),
+                state.ring,
+                models,
+            )
 
         new_state = EngineState(
             worker=wstate,
@@ -1555,7 +1582,7 @@ class TMSNEngine:
         k = int(self.config.publish_every_k)
         while self._next_publish_round <= rounds:
             self._next_publish_round += k
-        live = np.where(np.asarray(state.alive), np.asarray(state.certs), np.inf)
+        live = np.where(_to_host(state.alive), _to_host(state.certs), np.inf)
         best = int(np.argmin(live))
         best_cert = float(live[best])
         if not np.isfinite(best_cert):
@@ -1563,11 +1590,20 @@ class TMSNEngine:
         if best_cert >= self._published_cert - float(self.config.publish_eps):
             return
         models = self.worker.export_models(state.worker)
-        params = jax.tree_util.tree_map(lambda a: np.asarray(a[best]), models)
+        params = jax.tree_util.tree_map(lambda a: _to_host(a[best]), models)
         self._publisher.publish(params, cert=best_cert, round=rounds)
         self._published_cert = best_cert
 
     def run(self) -> SimResult:
+        """One whole run from a fresh initial state, recorded as a run of
+        :mod:`repro.core.telemetry`: the spans ``tmsn.init``,
+        ``tmsn.dispatch``, ``tmsn.fetch``, ``tmsn.host`` and
+        ``tmsn.finalize`` under ``tmsn.run``, and the counters
+        ``chunks``, ``rounds``, ``host_fetches`` and ``chunk_compiles``."""
+        with telemetry.run_scope():
+            return self._run()
+
+    def _run(self) -> SimResult:
         cfg = self.config
         if self._capacity is None:
             self._resolve_auto_capacity()
@@ -1575,8 +1611,9 @@ class TMSNEngine:
         # a finite best certificate publishes unconditionally
         self._published_cert = float("inf")
         self._next_publish_round = max(int(cfg.publish_every_k), 1)
-        state = self._init_state()
-        certs0 = np.asarray(state.certs)
+        with telemetry.span(telemetry.INIT):
+            state = self._init_state()
+            certs0 = _to_host(state.certs)
         history: list[tuple[float, int, float]] = [
             (0.0, i, float(certs0[i])) for i in range(cfg.n_workers)
         ]
@@ -1590,60 +1627,73 @@ class TMSNEngine:
         remaining = int(cfg.max_rounds)
         while remaining > 0:
             kk = min(k, remaining)
-            state, infos = self._chunk_fn(kk)(state)
+            with telemetry.span(telemetry.DISPATCH):
+                state, infos = self._chunk_fn(kk, state)(state)
+            telemetry.count("chunks")
             remaining -= kk
             if not fetch:
-                rounds += kk
-                self._maybe_publish(state, rounds)
+                with telemetry.span(telemetry.HOST):
+                    rounds += kk
+                    self._maybe_publish(state, rounds)
                 continue
-            certs_k = np.asarray(infos.certs)  # (kk, W)
-            stop = None
-            if cfg.target_certificate is not None:
-                # f32 target, matching the in-scan freeze comparison —
-                # a float64 host compare could disagree with the device
-                # in the ULP window around a non-f32-representable target
-                hit = np.any(
-                    (certs_k <= np.float32(cfg.target_certificate))
-                    & np.asarray(infos.alive),
-                    axis=1,
-                )
-                if hit.any():
-                    stop = int(np.argmax(hit))
-            last = kk - 1 if stop is None else stop
-            rounds += last + 1
-            if cfg.record_history:
-                # bulk append over the stacked chunk: row-major nonzero
-                # keeps (round, worker) order identical to the old
-                # per-round per-worker Python loop
-                changed_k = np.asarray(infos.changed)
-                clock_k = np.asarray(infos.clock)
-                rr, ww = np.nonzero(changed_k[: last + 1])
-                history.extend(
-                    zip(clock_k[rr, ww].tolist(), ww.tolist(), certs_k[rr, ww].tolist())
-                )
-            self._maybe_publish(state, rounds)
+            with telemetry.span(telemetry.FETCH):
+                certs_k = _to_host(infos.certs)  # (kk, W)
+                if cfg.target_certificate is not None:
+                    alive_k = _to_host(infos.alive)
+                if cfg.record_history:
+                    changed_k = _to_host(infos.changed)
+                    clock_k = _to_host(infos.clock)
+            with telemetry.span(telemetry.HOST):
+                stop = None
+                if cfg.target_certificate is not None:
+                    # f32 target, matching the in-scan freeze comparison —
+                    # a float64 host compare could disagree with the device
+                    # in the ULP window around a non-f32-representable target
+                    hit = np.any(
+                        (certs_k <= np.float32(cfg.target_certificate)) & alive_k,
+                        axis=1,
+                    )
+                    if hit.any():
+                        stop = int(np.argmax(hit))
+                last = kk - 1 if stop is None else stop
+                rounds += last + 1
+                if cfg.record_history:
+                    # bulk append over the stacked chunk: row-major nonzero
+                    # keeps (round, worker) order identical to the old
+                    # per-round per-worker Python loop
+                    rr, ww = np.nonzero(changed_k[: last + 1])
+                    history.extend(
+                        zip(clock_k[rr, ww].tolist(), ww.tolist(), certs_k[rr, ww].tolist())
+                    )
+                self._maybe_publish(state, rounds)
             if stop is not None:
                 break
+        telemetry.count("rounds", rounds)
+        with telemetry.span(telemetry.FINALIZE):
+            return self._finalize(state, history, rounds)
+
+    def _finalize(self, state: EngineState, history: list, rounds: int) -> SimResult:
+        cfg = self.config
         # final flush: a last-chunk improvement between cadence points
         # still reaches the serving tier before run() returns
         self._maybe_publish(state, rounds, final=True)
 
-        certs = np.asarray(state.certs)
+        certs = _to_host(state.certs)
         models = self.worker.export_models(state.worker)
         # counters are () scalars on the single-device engine and
         # (n_devices,) per-shard partials on the sharded one; np.sum
         # covers both (the per-shard reduction happens here, once)
         ictrl, dctrl = self._control_split()
         traffic = TrafficCounters.from_shards(
-            sent=np.asarray(state.sent),
-            accepted=np.asarray(state.accepted),
-            discarded=np.asarray(state.discarded),
+            sent=_to_host(state.sent),
+            accepted=_to_host(state.accepted),
+            discarded=_to_host(state.discarded),
             payload_bytes=self._payload_bytes,
-            sent_dcn=np.asarray(state.sent_dcn),
-            evicted=np.asarray(state.evicted),
+            sent_dcn=_to_host(state.sent_dcn),
+            evicted=_to_host(state.evicted),
             control_bytes=(ictrl + dctrl) * rounds,
-            dropped_injected=np.asarray(state.dropped_inj),
-            corrupt_rejected=np.asarray(state.corrupt_rej),
+            dropped_injected=_to_host(state.dropped_inj),
+            corrupt_rejected=_to_host(state.corrupt_rej),
         )
         # a join "happened" when its spare went live strictly after
         # round 0 and before the run ended (k=1 joins are full members
@@ -1661,15 +1711,15 @@ class TMSNEngine:
             history=history,
             final_certificates=[float(c) for c in certs],
             final_models=final_models,
-            sim_time=float(np.asarray(state.clock).max()),
-            cost_units_total=float(np.sum(np.asarray(state.cost_total))),
+            sim_time=float(_to_host(state.clock).max()),
+            cost_units_total=float(np.sum(_to_host(state.cost_total))),
             events_processed=rounds * cfg.n_workers,
             rounds=rounds,
             gossip_bytes_per_round=ici_bytes + dcn_bytes,
             gossip_bytes_per_round_ici=ici_bytes,
             gossip_bytes_per_round_dcn=dcn_bytes,
             gossip_mode=self._gossip_mode(),
-            inflight_occupancy_peak=int(np.max(np.asarray(state.occ_peak))),
+            inflight_occupancy_peak=int(np.max(_to_host(state.occ_peak))),
             control_bytes_per_round=ictrl + dctrl,
             control_plane=cfg.control_plane,
             inflight_capacity_selected=self._auto_selected,

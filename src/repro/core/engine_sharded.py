@@ -159,11 +159,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core import telemetry
 from repro.core.engine import (
     EngineConfig,
     EngineState,
     RoundInfo,
     TMSNEngine,
+    _compile,
     _dense_push_candidates,
     _inject_faults,
     _queue_push,
@@ -171,6 +173,13 @@ from repro.core.engine import (
 )
 from repro.core.protocol import accepts, improves
 from repro.core.worker import BatchedTMSNWorker, bind_shared_data, export_payload_rows
+
+
+def _gossip_gather(tree, axes):
+    """The tiled ``all_gather`` of one round's gossip, under the
+    ``tmsn.gossip`` scope."""
+    with jax.named_scope(telemetry.GOSSIP):
+        return jax.lax.all_gather(tree, axes, axis=0, tiled=True)
 
 
 class _ShardConsts(NamedTuple):
@@ -225,7 +234,7 @@ class ShardedTMSNEngine(TMSNEngine):
             )
 
     # ------------------------------------------------------------------
-    def _build_chunk(self, length: int):
+    def _build_chunk(self, length: int, state: EngineState):
         """Chunk dispatcher: the whole K-round ``lax.scan`` runs inside
         one ``shard_map`` region (collectives and the cross-shard
         target-crossing psum stay inside the compiled program)."""
@@ -287,6 +296,14 @@ class ShardedTMSNEngine(TMSNEngine):
 
         # the worker's shared data is replicated: every device reads all of it
         shared_specs = jax.tree_util.tree_map(lambda _: P(), self._shared)
+        consts = _ShardConsts(
+            speed=self._speed,
+            speed_norm=self._speed_norm,
+            fail_round=self._fail_round,
+            # delay is stored [src, dst]; the step indexes [local dst, src]
+            delay_t=jnp.transpose(self._delay),
+            join_round=self._join_round,
+        )
         step = jax.jit(
             jax.shard_map(
                 chunk_local,
@@ -296,14 +313,7 @@ class ShardedTMSNEngine(TMSNEngine):
                 check_vma=False,
             )
         )
-        consts = _ShardConsts(
-            speed=self._speed,
-            speed_norm=self._speed_norm,
-            fail_round=self._fail_round,
-            # delay is stored [src, dst]; the step indexes [local dst, src]
-            delay_t=jnp.transpose(self._delay),
-            join_round=self._join_round,
-        )
+        step = _compile(step, state, consts, self._shared)
         return lambda state: step(state, consts, self._shared)
 
     def _init_state(self) -> EngineState:
@@ -428,75 +438,80 @@ class ShardedTMSNEngine(TMSNEngine):
         # --- 1. deliver arrivals due this round (all-local: both
         # representations are destination-sharded with a global source
         # axis) --------------------------------------------------------------
-        if self._capacity:
-            # sparse: delivery argmin + accept gate + laggard credit are
-            # one fused kernel call on the (wl, C) pending queue; the
-            # queue stores the ring slot, so no delay lookup is needed
-            (
-                inflight,
-                best_cert,
-                best_src,
-                sent_slot,
-                take,
-                n_arrivals,
-                credit,
-                active,
-            ) = self._deliver_sparse(
-                state.inflight, certs0, alive, credit_in, consts.speed_norm, r
-            )
-        else:
-            arr = state.inflight[:, :, 0]  # (wl dst, W src) certs
-            arr_live = jnp.where(alive[:, None], arr, jnp.inf)
-            best_src = jnp.argmin(arr_live, axis=1)  # (wl,) global src ids
-            best_cert = arr_live[row_idx, best_src]
-            take = accepts(certs0, best_cert, cfg.eps) & jnp.isfinite(best_cert)
-            n_arrivals = jnp.sum(jnp.isfinite(arr), dtype=jnp.int32)
-            sent_slot = (r - consts.delay_t[row_idx, best_src]) % depth
+        with jax.named_scope(telemetry.DELIVER):
+            if self._capacity:
+                # sparse: delivery argmin + accept gate + laggard credit are
+                # one fused kernel call on the (wl, C) pending queue; the
+                # queue stores the ring slot, so no delay lookup is needed
+                (
+                    inflight,
+                    best_cert,
+                    best_src,
+                    sent_slot,
+                    take,
+                    n_arrivals,
+                    credit,
+                    active,
+                ) = self._deliver_sparse(
+                    state.inflight, certs0, alive, credit_in, consts.speed_norm, r
+                )
+            else:
+                arr = state.inflight[:, :, 0]  # (wl dst, W src) certs
+                arr_live = jnp.where(alive[:, None], arr, jnp.inf)
+                best_src = jnp.argmin(arr_live, axis=1)  # (wl,) global src ids
+                best_cert = arr_live[row_idx, best_src]
+                take = accepts(certs0, best_cert, cfg.eps) & jnp.isfinite(best_cert)
+                n_arrivals = jnp.sum(jnp.isfinite(arr), dtype=jnp.int32)
+                sent_slot = (r - consts.delay_t[row_idx, best_src]) % depth
         n_taken = jnp.sum(take, dtype=jnp.int32)
-        in_models = jax.tree_util.tree_map(
-            lambda a: a[sent_slot, best_src], state.ring
-        )
+        with jax.named_scope(telemetry.ADOPT):
+            in_models = jax.tree_util.tree_map(
+                lambda a: a[sent_slot, best_src], state.ring
+            )
 
-        def _adopt(operand):
-            wstate, models, c, t = operand
-            return self.worker.adopt_batch(wstate, models, c, t)
+            def _adopt(operand):
+                wstate, models, c, t = operand
+                return self.worker.adopt_batch(wstate, models, c, t)
 
-        # per-shard cond: a shard with no taker skips the adopt math
-        wstate, adopt_cost = jax.lax.cond(
-            jnp.any(take),
-            _adopt,
-            lambda operand: (operand[0], jnp.zeros((wl,), jnp.float32)),
-            (state.worker, in_models, best_cert, take),
-        )
+            # per-shard cond: a shard with no taker skips the adopt math
+            wstate, adopt_cost = jax.lax.cond(
+                jnp.any(take),
+                _adopt,
+                lambda operand: (operand[0], jnp.zeros((wl,), jnp.float32)),
+                (state.worker, in_models, best_cert, take),
+            )
 
         # --- 2.+3. shift the dense buffer, accrue compute credit (both
         # already folded into the fused kernel on the sparse path) ----------
-        if not self._capacity:
-            inflight = jnp.concatenate(
-                [state.inflight[:, :, 1:], jnp.full((wl, w, 1), jnp.inf, jnp.float32)],
-                axis=2,
-            )
-            credit = credit_in + consts.speed_norm
-            active = alive & (credit >= 1.0 - 1e-6)
-            credit = jnp.where(active, credit - 1.0, credit)
+        with jax.named_scope(telemetry.DELIVER):
+            if not self._capacity:
+                inflight = jnp.concatenate(
+                    [state.inflight[:, :, 1:], jnp.full((wl, w, 1), jnp.inf, jnp.float32)],
+                    axis=2,
+                )
+                credit = credit_in + consts.speed_norm
+                active = alive & (credit >= 1.0 - 1e-6)
+                credit = jnp.where(active, credit - 1.0, credit)
 
         # optional resample hooks: statically absent for workers
         # without a sampling phase (repro.core.worker.has_resample_hooks)
-        if self._has_resample:
-            need = self.worker.needs_resample(wstate) & active
-            wstate, resample_cost = jax.lax.cond(
-                jnp.any(need),
-                lambda op: self.worker.resample_round(op[0], op[1]),
-                lambda op: (op[0], jnp.zeros((wl,), jnp.float32)),
-                (wstate, need),
-            )
-            scan_mask = active & ~need
-        else:
-            resample_cost = jnp.zeros((wl,), jnp.float32)
-            scan_mask = active
-        certs_pre = self.worker.certificates(wstate)
-        wstate, scan_cost, fired = self.worker.scan_round(wstate, scan_mask)
-        certs = self.worker.certificates(wstate)
+        with jax.named_scope(telemetry.RESAMPLE):
+            if self._has_resample:
+                need = self.worker.needs_resample(wstate) & active
+                wstate, resample_cost = jax.lax.cond(
+                    jnp.any(need),
+                    lambda op: self.worker.resample_round(op[0], op[1]),
+                    lambda op: (op[0], jnp.zeros((wl,), jnp.float32)),
+                    (wstate, need),
+                )
+                scan_mask = active & ~need
+            else:
+                resample_cost = jnp.zeros((wl,), jnp.float32)
+                scan_mask = active
+        with jax.named_scope(telemetry.SCAN):
+            certs_pre = self.worker.certificates(wstate)
+            wstate, scan_cost, fired = self.worker.scan_round(wstate, scan_mask)
+            certs = self.worker.certificates(wstate)
 
         cost = adopt_cost + resample_cost + scan_cost
         clock = state.clock + cost / jnp.maximum(consts.speed, 1e-12)
@@ -515,308 +530,87 @@ class ShardedTMSNEngine(TMSNEngine):
         # on a pod mesh it spans one pod, and (dense control only) the
         # gathered (W_pod,) control plane is scattered into the
         # (W,)-wide arrays at the pod's contiguous global-id block ----------
-        improved = fired & improves(certs_pre, certs, 0.0) & scan_mask
-        w_tier = w // self._n_pods  # workers visible to the intra tier
-        pod_idx = jax.lax.axis_index("pod") if self._n_pods > 1 else None
-        n_evicted = jnp.zeros((), jnp.int32)
-        occ_pre_max = jnp.zeros((), jnp.int32)
-        n_dropped = jnp.zeros((), jnp.int32)
-        n_rejected = jnp.zeros((), jnp.int32)
-        if self._control_sparse:
-            kc = min(int(cfg.gossip_top_k), wl)
-            cand_rows, cand_valid = self._top_k_candidates(improved, certs, kc)
-            cand_ids = jnp.where(cand_valid, local_ids[cand_rows], w)
-            cand_certs = jnp.where(cand_valid, certs[cand_rows], jnp.inf)
-            if cfg.gossip_mode == "gated":
-                # one collective: the (k,) control triples and the (k,)
-                # candidate payloads ride together
-                gathered = jax.lax.all_gather(
-                    {
-                        "certs": cand_certs,
-                        "ids": cand_ids,
-                        "models": self._export_rows(wstate, cand_rows),
-                    },
-                    "workers",
-                    axis=0,
-                    tiled=True,
-                )  # every leg (wpp * kc, ...)
-                ring = jax.tree_util.tree_map(
-                    lambda buf, m: buf.at[r % depth, gathered["ids"]].set(
-                        m, mode="drop"
-                    ),
-                    state.ring,
-                    gathered["models"],
-                )
-            else:
-                # dense payload plane, sparse control plane: every tier
-                # worker's model still gathers, but only candidate rows
-                # are ever referenced by the in-flight state, so only
-                # those ring rows are written (scattered by global id;
-                # invalid candidates point out of bounds and drop)
-                gathered = jax.lax.all_gather(
-                    {
-                        "certs": cand_certs,
-                        "ids": cand_ids,
-                        "models": self.worker.export_models(wstate),
-                    },
-                    "workers",
-                    axis=0,
-                    tiled=True,
-                )  # certs/ids: (wpp * kc,); models: (w_tier, ...)
-                base = 0 if self._n_pods == 1 else pod_idx * w_tier
-                rows_t = jnp.clip(gathered["ids"] - base, 0, w_tier - 1)
-                ring = jax.tree_util.tree_map(
-                    lambda buf, m: buf.at[r % depth, gathered["ids"]].set(
-                        m[rows_t], mode="drop"
-                    ),
-                    state.ring,
-                    gathered["models"],
-                )
-            if self._capacity:
-                (
-                    inflight,
-                    n_pushed,
-                    n_evicted,
-                    occ_pre_max,
-                    n_dropped,
-                    n_rejected,
-                ) = _queue_push_candidates(
-                    inflight,
-                    gathered["certs"],
-                    gathered["ids"],
-                    alive,
-                    local_ids,
-                    consts.delay_t,
-                    r,
-                    depth,
-                    cfg.round_step_impl,
-                    dst_cert=certs,
-                    fault=self._fault,
-                    pod_of=self._pod_of,
-                )
-            else:
-                inflight, n_pushed, n_dropped, n_rejected = _dense_push_candidates(
-                    inflight,
-                    gathered["certs"],
-                    gathered["ids"],
-                    alive,
-                    local_ids,
-                    consts.delay_t,
-                    r=r,
-                    dst_cert=certs,
-                    fault=self._fault,
-                    pod_of=self._pod_of,
-                )
-        elif cfg.gossip_mode == "gated":
-            k = min(int(cfg.gossip_top_k), wl)
-            cand_rows, cand_valid = self._top_k_candidates(improved, certs, k)
-            bcast = jnp.zeros((wl,), bool).at[cand_rows].set(cand_valid)
-            # ONE collective: tiled gathers are per-leaf, so the (wl,)
-            # control plane and the (k,) payload leg ride together —
-            # at gated payload sizes the per-collective launch latency
-            # is the cost that matters
-            gathered = jax.lax.all_gather(
-                {
-                    "certs": certs,
-                    "bcast": bcast,
-                    # un-improved candidate slots point out of bounds so
-                    # the ring scatter drops them
-                    "ids": jnp.where(cand_valid, local_ids[cand_rows], w),
-                    "models": self._export_rows(wstate, cand_rows),
-                },
-                "workers",
-                axis=0,
-                tiled=True,
-            )  # certs/bcast: (w_tier,); ids/models: (wpp * k, ...)
-            tier_certs, tier_bcast = gathered["certs"], gathered["bcast"]
-            ring = jax.tree_util.tree_map(
-                lambda buf, m: buf.at[r % depth, gathered["ids"]].set(m, mode="drop"),
-                state.ring,
-                gathered["models"],
-            )
-        else:
-            gathered = jax.lax.all_gather(
-                {
-                    "certs": certs,
-                    "improved": improved,
-                    "models": self.worker.export_models(wstate),
-                },
-                "workers",
-                axis=0,
-                tiled=True,
-            )
-            tier_certs, tier_bcast = gathered["certs"], gathered["improved"]
-            # ring writes gated to broadcasters (only their entries are
-            # ever read back), mirroring the single-device engine
-            if self._n_pods == 1:
-                ring = jax.tree_util.tree_map(
-                    lambda buf, m: buf.at[r % depth].set(
-                        jnp.where(
-                            tier_bcast.reshape((-1,) + (1,) * (m.ndim - 1)),
-                            m,
-                            buf[r % depth],
-                        )
-                    ),
-                    state.ring,
-                    gathered["models"],
-                )
-
-        if not self._control_sparse:
-            if self._n_pods == 1:
-                certs_all, bcast_all = tier_certs, tier_bcast  # (W,)
-            else:
-                # scatter the pod-local control plane into global width;
-                # pod p owns the contiguous global-id block
-                # [p * W_pod, (p + 1) * W_pod)
-                pod_gids = pod_idx * w_tier + jnp.arange(w_tier)
-                certs_all = (
-                    jnp.full((w,), jnp.inf, jnp.float32).at[pod_gids].set(tier_certs)
-                )
-                bcast_all = jnp.zeros((w,), bool).at[pod_gids].set(tier_bcast)
-                if cfg.gossip_mode != "gated":
-                    # dense intra-pod ring writes, scattered by global id
-                    # into this pod's private ring replica (silent workers
-                    # point out of bounds and drop)
-                    ids = jnp.where(tier_bcast, pod_gids, w)
+        with jax.named_scope(telemetry.BROADCAST):
+            improved = fired & improves(certs_pre, certs, 0.0) & scan_mask
+            w_tier = w // self._n_pods  # workers visible to the intra tier
+            pod_idx = jax.lax.axis_index("pod") if self._n_pods > 1 else None
+            n_evicted = jnp.zeros((), jnp.int32)
+            occ_pre_max = jnp.zeros((), jnp.int32)
+            n_dropped = jnp.zeros((), jnp.int32)
+            n_rejected = jnp.zeros((), jnp.int32)
+            if self._control_sparse:
+                kc = min(int(cfg.gossip_top_k), wl)
+                cand_rows, cand_valid = self._top_k_candidates(improved, certs, kc)
+                cand_ids = jnp.where(cand_valid, local_ids[cand_rows], w)
+                cand_certs = jnp.where(cand_valid, certs[cand_rows], jnp.inf)
+                if cfg.gossip_mode == "gated":
+                    # one collective: the (k,) control triples and the (k,)
+                    # candidate payloads ride together
+                    gathered = _gossip_gather(
+                        {
+                            "certs": cand_certs,
+                            "ids": cand_ids,
+                            "models": self._export_rows(wstate, cand_rows),
+                        },
+                        "workers",
+                    )  # every leg (wpp * kc, ...)
                     ring = jax.tree_util.tree_map(
-                        lambda buf, m: buf.at[r % depth, ids].set(m, mode="drop"),
+                        lambda buf, m: buf.at[r % depth, gathered["ids"]].set(
+                            m, mode="drop"
+                        ),
                         state.ring,
                         gathered["models"],
                     )
-
-            if self._capacity:
-                # tier-1 push into the (wl, C) pending queues: the
-                # gathered control plane is dense-width in both gossip
-                # modes, so one (W,) candidate score serves dense and
-                # gated alike; on a pod mesh bcast_all is zero outside
-                # this pod
-                (
-                    inflight,
-                    n_pushed,
-                    n_evicted,
-                    occ_pre_max,
-                    n_dropped,
-                    n_rejected,
-                ) = _queue_push(
-                    inflight,
-                    jnp.where(bcast_all, certs_all, jnp.inf),
-                    alive,
-                    local_ids,
-                    consts.delay_t,
-                    r,
-                    depth,
-                    dst_cert=certs,
-                    fault=self._fault,
-                    pod_of=self._pod_of,
-                )
-            elif self._fault is None:
-                d_idx = jnp.arange(depth)[None, None, :]
-                # push_mask[local dst, global src, d]; on a pod mesh
-                # bcast_all is zero outside this pod, so tier-1 pushes
-                # stay intra-pod
-                push_mask = (
-                    bcast_all[None, :, None]
-                    & alive[:, None, None]
-                    & (local_ids[:, None] != jnp.arange(w)[None, :])[:, :, None]
-                    & (d_idx == (consts.delay_t[:, :, None] - 1))
-                )
-                inflight = jnp.where(push_mask, certs_all[None, :, None], inflight)
-                n_pushed = jnp.sum(push_mask, dtype=jnp.int32)
-            else:
-                # faulted dense push: per-edge (wl, W) certificate matrix
-                # so _inject_faults can drop/corrupt/reject single edges
-                # (mirrors the single-device engine's faulted branch)
-                push2 = (
-                    bcast_all[None, :]
-                    & alive[:, None]
-                    & (local_ids[:, None] != jnp.arange(w)[None, :])
-                )
-                cert_mat = jnp.where(push2, certs_all[None, :], jnp.inf)
-                src_mat = jnp.broadcast_to(
-                    jnp.arange(w, dtype=jnp.int32)[None, :], (wl, w)
-                )
-                cert_mat, _, _, n_dropped, n_rejected = _inject_faults(
-                    self._fault,
-                    self._pod_of,
-                    r,
-                    local_ids.astype(jnp.int32),
-                    src_mat,
-                    cert_mat,
-                    None,
-                    certs,
-                    depth,
-                )
-                d_idx = jnp.arange(depth)[None, None, :]
-                push_mask = jnp.isfinite(cert_mat)[:, :, None] & (
-                    d_idx == (consts.delay_t[:, :, None] - 1)
-                )
-                inflight = jnp.where(push_mask, cert_mat[:, :, None], inflight)
-                n_pushed = jnp.sum(push2, dtype=jnp.int32)  # logical sends
-
-        # --- gossip, tier 2 (cross-pod, DCN): improvements accumulate
-        # in the pending mask and the freshest certificates flush over
-        # the ``pod`` axis every cross_pod_every_k rounds — the paper's
-        # "tell me something new" applied to the interconnect hierarchy.
-        # Each device ships its top cross_pod_top_k pending candidates
-        # (the PR 3 gated payload path); receivers scatter the payloads
-        # into their pod's ring replica and push the certificates into
-        # the in-flight buffer for cross-pod destinations only (same-pod
-        # destinations already got them from tier 1) ------------------------
-        xpend = state.xpend
-        n_pushed_x = jnp.zeros((), jnp.int32)
-        if self._n_pods > 1:
-            xpend = xpend | improved
-            kx = min(int(cfg.cross_pod_top_k), wl)
-            src_pod = jnp.arange(w) // w_tier  # (W,) pod of each global id
-
-            def _flush(args):
-                xpend, inflight, ring = args
-                rows, valid = self._top_k_candidates(xpend, certs, kx)
-                gx = jax.lax.all_gather(
-                    {
-                        "certs": certs[rows],
-                        "ids": jnp.where(valid, local_ids[rows], w),
-                        "models": self._export_rows(wstate, rows),
-                    },
-                    ("pod", "workers"),
-                    axis=0,
-                    tiled=True,
-                )  # (n_dev * kx, ...), flat-device order (pod-major)
-                ring = jax.tree_util.tree_map(
-                    lambda buf, m: buf.at[r % depth, gx["ids"]].set(m, mode="drop"),
-                    ring,
-                    gx["models"],
-                )
-                flushed = jnp.zeros((wl,), bool).at[rows].set(valid)
-                if self._control_sparse:
-                    # sparse control: push the gathered flush candidates
-                    # directly by global id — no (W,)-wide scatter. The
-                    # cross-pod mask (same-pod destinations already
-                    # heard tier 1) folds into candidate validity.
-                    pod_of = jnp.clip(gx["ids"], 0, w - 1) // w_tier
-                    valid_x = (gx["ids"] < w) & (pod_of != pod_idx)
-                    ids_x = jnp.where(valid_x, gx["ids"], w)
-                    certs_x = jnp.where(valid_x, gx["certs"], jnp.inf)
-                    if self._capacity:
-                        inflight, nx, ne, occ, nd, nr = _queue_push_candidates(
-                            inflight,
-                            certs_x,
-                            ids_x,
-                            alive,
-                            local_ids,
-                            consts.delay_t,
-                            r,
-                            depth,
-                            cfg.round_step_impl,
-                            dst_cert=certs,
-                            fault=self._fault,
-                            pod_of=self._pod_of,
-                        )
-                        return (xpend & ~flushed, inflight, ring, nx, ne, occ, nd, nr)
-                    inflight, nx, nd, nr = _dense_push_candidates(
+                else:
+                    # dense payload plane, sparse control plane: every tier
+                    # worker's model still gathers, but only candidate rows
+                    # are ever referenced by the in-flight state, so only
+                    # those ring rows are written (scattered by global id;
+                    # invalid candidates point out of bounds and drop)
+                    gathered = _gossip_gather(
+                        {
+                            "certs": cand_certs,
+                            "ids": cand_ids,
+                            "models": self.worker.export_models(wstate),
+                        },
+                        "workers",
+                    )  # certs/ids: (wpp * kc,); models: (w_tier, ...)
+                    base = 0 if self._n_pods == 1 else pod_idx * w_tier
+                    rows_t = jnp.clip(gathered["ids"] - base, 0, w_tier - 1)
+                    ring = jax.tree_util.tree_map(
+                        lambda buf, m: buf.at[r % depth, gathered["ids"]].set(
+                            m[rows_t], mode="drop"
+                        ),
+                        state.ring,
+                        gathered["models"],
+                    )
+                if self._capacity:
+                    (
                         inflight,
-                        certs_x,
-                        ids_x,
+                        n_pushed,
+                        n_evicted,
+                        occ_pre_max,
+                        n_dropped,
+                        n_rejected,
+                    ) = _queue_push_candidates(
+                        inflight,
+                        gathered["certs"],
+                        gathered["ids"],
+                        alive,
+                        local_ids,
+                        consts.delay_t,
+                        r,
+                        depth,
+                        cfg.round_step_impl,
+                        dst_cert=certs,
+                        fault=self._fault,
+                        pod_of=self._pod_of,
+                    )
+                else:
+                    inflight, n_pushed, n_dropped, n_rejected = _dense_push_candidates(
+                        inflight,
+                        gathered["certs"],
+                        gathered["ids"],
                         alive,
                         local_ids,
                         consts.delay_t,
@@ -825,25 +619,95 @@ class ShardedTMSNEngine(TMSNEngine):
                         fault=self._fault,
                         pod_of=self._pod_of,
                     )
-                    z = jnp.zeros((), jnp.int32)
-                    return (xpend & ~flushed, inflight, ring, nx, z, z, nd, nr)
-                xcerts = (
-                    jnp.full((w,), jnp.inf, jnp.float32)
-                    .at[gx["ids"]]
-                    .set(gx["certs"], mode="drop")
+            elif cfg.gossip_mode == "gated":
+                k = min(int(cfg.gossip_top_k), wl)
+                cand_rows, cand_valid = self._top_k_candidates(improved, certs, k)
+                bcast = jnp.zeros((wl,), bool).at[cand_rows].set(cand_valid)
+                # ONE collective: tiled gathers are per-leaf, so the (wl,)
+                # control plane and the (k,) payload leg ride together —
+                # at gated payload sizes the per-collective launch latency
+                # is the cost that matters
+                gathered = _gossip_gather(
+                    {
+                        "certs": certs,
+                        "bcast": bcast,
+                        # un-improved candidate slots point out of bounds so
+                        # the ring scatter drops them
+                        "ids": jnp.where(cand_valid, local_ids[cand_rows], w),
+                        "models": self._export_rows(wstate, cand_rows),
+                    },
+                    "workers",
+                )  # certs/bcast: (w_tier,); ids/models: (wpp * k, ...)
+                tier_certs, tier_bcast = gathered["certs"], gathered["bcast"]
+                ring = jax.tree_util.tree_map(
+                    lambda buf, m: buf.at[r % depth, gathered["ids"]].set(m, mode="drop"),
+                    state.ring,
+                    gathered["models"],
                 )
-                xbcast = (
-                    jnp.zeros((w,), bool)
-                    .at[gx["ids"]]
-                    .set(jnp.ones_like(gx["ids"], bool), mode="drop")
+            else:
+                gathered = _gossip_gather(
+                    {
+                        "certs": certs,
+                        "improved": improved,
+                        "models": self.worker.export_models(wstate),
+                    },
+                    "workers",
                 )
+                tier_certs, tier_bcast = gathered["certs"], gathered["improved"]
+                # ring writes gated to broadcasters (only their entries are
+                # ever read back), mirroring the single-device engine
+                if self._n_pods == 1:
+                    ring = jax.tree_util.tree_map(
+                        lambda buf, m: buf.at[r % depth].set(
+                            jnp.where(
+                                tier_bcast.reshape((-1,) + (1,) * (m.ndim - 1)),
+                                m,
+                                buf[r % depth],
+                            )
+                        ),
+                        state.ring,
+                        gathered["models"],
+                    )
+
+            if not self._control_sparse:
+                if self._n_pods == 1:
+                    certs_all, bcast_all = tier_certs, tier_bcast  # (W,)
+                else:
+                    # scatter the pod-local control plane into global width;
+                    # pod p owns the contiguous global-id block
+                    # [p * W_pod, (p + 1) * W_pod)
+                    pod_gids = pod_idx * w_tier + jnp.arange(w_tier)
+                    certs_all = (
+                        jnp.full((w,), jnp.inf, jnp.float32).at[pod_gids].set(tier_certs)
+                    )
+                    bcast_all = jnp.zeros((w,), bool).at[pod_gids].set(tier_bcast)
+                    if cfg.gossip_mode != "gated":
+                        # dense intra-pod ring writes, scattered by global id
+                        # into this pod's private ring replica (silent workers
+                        # point out of bounds and drop)
+                        ids = jnp.where(tier_bcast, pod_gids, w)
+                        ring = jax.tree_util.tree_map(
+                            lambda buf, m: buf.at[r % depth, ids].set(m, mode="drop"),
+                            state.ring,
+                            gathered["models"],
+                        )
+
                 if self._capacity:
-                    # same queue push as tier 1, with the candidate score
-                    # masked to cross-pod sources (same-pod destinations
-                    # already heard these via tier 1)
-                    inflight, nx, ne, occ, nd, nr = _queue_push(
+                    # tier-1 push into the (wl, C) pending queues: the
+                    # gathered control plane is dense-width in both gossip
+                    # modes, so one (W,) candidate score serves dense and
+                    # gated alike; on a pod mesh bcast_all is zero outside
+                    # this pod
+                    (
                         inflight,
-                        jnp.where(xbcast & (src_pod != pod_idx), xcerts, jnp.inf),
+                        n_pushed,
+                        n_evicted,
+                        occ_pre_max,
+                        n_dropped,
+                        n_rejected,
+                    ) = _queue_push(
+                        inflight,
+                        jnp.where(bcast_all, certs_all, jnp.inf),
                         alive,
                         local_ids,
                         consts.delay_t,
@@ -853,82 +717,224 @@ class ShardedTMSNEngine(TMSNEngine):
                         fault=self._fault,
                         pod_of=self._pod_of,
                     )
-                    return (xpend & ~flushed, inflight, ring, nx, ne, occ, nd, nr)
-                z = jnp.zeros((), jnp.int32)
-                nd = nr = z
-                xpush2 = (
-                    xbcast[None, :]
-                    & alive[:, None]
-                    # only cross-pod destinations (self-exclusion implied)
-                    & (src_pod != pod_idx)[None, :]
-                )
-                xcert_mat = jnp.where(xpush2, xcerts[None, :], jnp.inf)
-                if self._fault is not None:
+                elif self._fault is None:
+                    d_idx = jnp.arange(depth)[None, None, :]
+                    # push_mask[local dst, global src, d]; on a pod mesh
+                    # bcast_all is zero outside this pod, so tier-1 pushes
+                    # stay intra-pod
+                    push_mask = (
+                        bcast_all[None, :, None]
+                        & alive[:, None, None]
+                        & (local_ids[:, None] != jnp.arange(w)[None, :])[:, :, None]
+                        & (d_idx == (consts.delay_t[:, :, None] - 1))
+                    )
+                    inflight = jnp.where(push_mask, certs_all[None, :, None], inflight)
+                    n_pushed = jnp.sum(push_mask, dtype=jnp.int32)
+                else:
+                    # faulted dense push: per-edge (wl, W) certificate matrix
+                    # so _inject_faults can drop/corrupt/reject single edges
+                    # (mirrors the single-device engine's faulted branch)
+                    push2 = (
+                        bcast_all[None, :]
+                        & alive[:, None]
+                        & (local_ids[:, None] != jnp.arange(w)[None, :])
+                    )
+                    cert_mat = jnp.where(push2, certs_all[None, :], jnp.inf)
                     src_mat = jnp.broadcast_to(
                         jnp.arange(w, dtype=jnp.int32)[None, :], (wl, w)
                     )
-                    xcert_mat, _, _, nd, nr = _inject_faults(
+                    cert_mat, _, _, n_dropped, n_rejected = _inject_faults(
                         self._fault,
                         self._pod_of,
                         r,
                         local_ids.astype(jnp.int32),
                         src_mat,
-                        xcert_mat,
+                        cert_mat,
                         None,
                         certs,
                         depth,
                     )
-                d_idx = jnp.arange(depth)[None, None, :]
-                xpush = jnp.isfinite(xcert_mat)[:, :, None] & (
-                    d_idx == (consts.delay_t[:, :, None] - 1)
-                )
-                inflight = jnp.where(xpush, xcert_mat[:, :, None], inflight)
-                return (
-                    xpend & ~flushed,
-                    inflight,
-                    ring,
-                    jnp.sum(xpush2, dtype=jnp.int32),
-                    z,
-                    z,
-                    nd,
-                    nr,
-                )
+                    d_idx = jnp.arange(depth)[None, None, :]
+                    push_mask = jnp.isfinite(cert_mat)[:, :, None] & (
+                        d_idx == (consts.delay_t[:, :, None] - 1)
+                    )
+                    inflight = jnp.where(push_mask, cert_mat[:, :, None], inflight)
+                    n_pushed = jnp.sum(push2, dtype=jnp.int32)  # logical sends
 
-            if int(cfg.cross_pod_every_k) == 1:
-                xpend, inflight, ring, n_pushed_x, ne_x, occ_x, nd_x, nr_x = _flush(
-                    (xpend, inflight, ring)
-                )
-            else:
-                # `r` is replicated, so every device takes the same
-                # branch and the pod-axis collective stays uniform
-                (
-                    xpend,
-                    inflight,
-                    ring,
-                    n_pushed_x,
-                    ne_x,
-                    occ_x,
-                    nd_x,
-                    nr_x,
-                ) = jax.lax.cond(
-                    (r % int(cfg.cross_pod_every_k)) == 0,
-                    _flush,
-                    lambda args: (
-                        args[0],
-                        args[1],
-                        args[2],
-                        jnp.zeros((), jnp.int32),
-                        jnp.zeros((), jnp.int32),
-                        jnp.zeros((), jnp.int32),
-                        jnp.zeros((), jnp.int32),
-                        jnp.zeros((), jnp.int32),
-                    ),
-                    (xpend, inflight, ring),
-                )
-            n_evicted = n_evicted + ne_x
-            occ_pre_max = jnp.maximum(occ_pre_max, occ_x)
-            n_dropped = n_dropped + nd_x
-            n_rejected = n_rejected + nr_x
+            # --- gossip, tier 2 (cross-pod, DCN): improvements accumulate
+            # in the pending mask and the freshest certificates flush over
+            # the ``pod`` axis every cross_pod_every_k rounds — the paper's
+            # "tell me something new" applied to the interconnect hierarchy.
+            # Each device ships its top cross_pod_top_k pending candidates
+            # (the PR 3 gated payload path); receivers scatter the payloads
+            # into their pod's ring replica and push the certificates into
+            # the in-flight buffer for cross-pod destinations only (same-pod
+            # destinations already got them from tier 1) ------------------------
+            xpend = state.xpend
+            n_pushed_x = jnp.zeros((), jnp.int32)
+            if self._n_pods > 1:
+                xpend = xpend | improved
+                kx = min(int(cfg.cross_pod_top_k), wl)
+                src_pod = jnp.arange(w) // w_tier  # (W,) pod of each global id
+
+                def _flush(args):
+                    xpend, inflight, ring = args
+                    rows, valid = self._top_k_candidates(xpend, certs, kx)
+                    gx = _gossip_gather(
+                        {
+                            "certs": certs[rows],
+                            "ids": jnp.where(valid, local_ids[rows], w),
+                            "models": self._export_rows(wstate, rows),
+                        },
+                        ("pod", "workers"),
+                    )  # (n_dev * kx, ...), flat-device order (pod-major)
+                    ring = jax.tree_util.tree_map(
+                        lambda buf, m: buf.at[r % depth, gx["ids"]].set(m, mode="drop"),
+                        ring,
+                        gx["models"],
+                    )
+                    flushed = jnp.zeros((wl,), bool).at[rows].set(valid)
+                    if self._control_sparse:
+                        # sparse control: push the gathered flush candidates
+                        # directly by global id — no (W,)-wide scatter. The
+                        # cross-pod mask (same-pod destinations already
+                        # heard tier 1) folds into candidate validity.
+                        pod_of = jnp.clip(gx["ids"], 0, w - 1) // w_tier
+                        valid_x = (gx["ids"] < w) & (pod_of != pod_idx)
+                        ids_x = jnp.where(valid_x, gx["ids"], w)
+                        certs_x = jnp.where(valid_x, gx["certs"], jnp.inf)
+                        if self._capacity:
+                            inflight, nx, ne, occ, nd, nr = _queue_push_candidates(
+                                inflight,
+                                certs_x,
+                                ids_x,
+                                alive,
+                                local_ids,
+                                consts.delay_t,
+                                r,
+                                depth,
+                                cfg.round_step_impl,
+                                dst_cert=certs,
+                                fault=self._fault,
+                                pod_of=self._pod_of,
+                            )
+                            return (xpend & ~flushed, inflight, ring, nx, ne, occ, nd, nr)
+                        inflight, nx, nd, nr = _dense_push_candidates(
+                            inflight,
+                            certs_x,
+                            ids_x,
+                            alive,
+                            local_ids,
+                            consts.delay_t,
+                            r=r,
+                            dst_cert=certs,
+                            fault=self._fault,
+                            pod_of=self._pod_of,
+                        )
+                        z = jnp.zeros((), jnp.int32)
+                        return (xpend & ~flushed, inflight, ring, nx, z, z, nd, nr)
+                    xcerts = (
+                        jnp.full((w,), jnp.inf, jnp.float32)
+                        .at[gx["ids"]]
+                        .set(gx["certs"], mode="drop")
+                    )
+                    xbcast = (
+                        jnp.zeros((w,), bool)
+                        .at[gx["ids"]]
+                        .set(jnp.ones_like(gx["ids"], bool), mode="drop")
+                    )
+                    if self._capacity:
+                        # same queue push as tier 1, with the candidate score
+                        # masked to cross-pod sources (same-pod destinations
+                        # already heard these via tier 1)
+                        inflight, nx, ne, occ, nd, nr = _queue_push(
+                            inflight,
+                            jnp.where(xbcast & (src_pod != pod_idx), xcerts, jnp.inf),
+                            alive,
+                            local_ids,
+                            consts.delay_t,
+                            r,
+                            depth,
+                            dst_cert=certs,
+                            fault=self._fault,
+                            pod_of=self._pod_of,
+                        )
+                        return (xpend & ~flushed, inflight, ring, nx, ne, occ, nd, nr)
+                    z = jnp.zeros((), jnp.int32)
+                    nd = nr = z
+                    xpush2 = (
+                        xbcast[None, :]
+                        & alive[:, None]
+                        # only cross-pod destinations (self-exclusion implied)
+                        & (src_pod != pod_idx)[None, :]
+                    )
+                    xcert_mat = jnp.where(xpush2, xcerts[None, :], jnp.inf)
+                    if self._fault is not None:
+                        src_mat = jnp.broadcast_to(
+                            jnp.arange(w, dtype=jnp.int32)[None, :], (wl, w)
+                        )
+                        xcert_mat, _, _, nd, nr = _inject_faults(
+                            self._fault,
+                            self._pod_of,
+                            r,
+                            local_ids.astype(jnp.int32),
+                            src_mat,
+                            xcert_mat,
+                            None,
+                            certs,
+                            depth,
+                        )
+                    d_idx = jnp.arange(depth)[None, None, :]
+                    xpush = jnp.isfinite(xcert_mat)[:, :, None] & (
+                        d_idx == (consts.delay_t[:, :, None] - 1)
+                    )
+                    inflight = jnp.where(xpush, xcert_mat[:, :, None], inflight)
+                    return (
+                        xpend & ~flushed,
+                        inflight,
+                        ring,
+                        jnp.sum(xpush2, dtype=jnp.int32),
+                        z,
+                        z,
+                        nd,
+                        nr,
+                    )
+
+                if int(cfg.cross_pod_every_k) == 1:
+                    xpend, inflight, ring, n_pushed_x, ne_x, occ_x, nd_x, nr_x = _flush(
+                        (xpend, inflight, ring)
+                    )
+                else:
+                    # `r` is replicated, so every device takes the same
+                    # branch and the pod-axis collective stays uniform
+                    (
+                        xpend,
+                        inflight,
+                        ring,
+                        n_pushed_x,
+                        ne_x,
+                        occ_x,
+                        nd_x,
+                        nr_x,
+                    ) = jax.lax.cond(
+                        (r % int(cfg.cross_pod_every_k)) == 0,
+                        _flush,
+                        lambda args: (
+                            args[0],
+                            args[1],
+                            args[2],
+                            jnp.zeros((), jnp.int32),
+                            jnp.zeros((), jnp.int32),
+                            jnp.zeros((), jnp.int32),
+                            jnp.zeros((), jnp.int32),
+                            jnp.zeros((), jnp.int32),
+                        ),
+                        (xpend, inflight, ring),
+                    )
+                n_evicted = n_evicted + ne_x
+                occ_pre_max = jnp.maximum(occ_pre_max, occ_x)
+                n_dropped = n_dropped + nd_x
+                n_rejected = n_rejected + nr_x
 
         new_state = EngineState(
             worker=wstate,

@@ -133,6 +133,7 @@ def edge_scan(
 
     hist, scal = pl.pallas_call(
         _edge_scan_kernel,
+        name="edge_scan",
         grid=(steps,),
         in_specs=[
             pl.BlockSpec((tile_n, d), lambda i: (i, 0)),

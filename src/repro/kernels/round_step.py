@@ -202,6 +202,7 @@ def queue_ingest(
     cand_spec = pl.BlockSpec((tile_w, m), row)
     out = pl.pallas_call(
         _queue_ingest_kernel,
+        name="queue_ingest",
         grid=(steps,),
         in_specs=[queue_spec] * 4 + [cand_spec] * 4,
         out_specs=[queue_spec] * 4,
@@ -268,6 +269,7 @@ def round_step(
     vec_spec = pl.BlockSpec((tile_w, 1), row)
     out = pl.pallas_call(
         functools.partial(_round_step_kernel, eps=eps),
+        name="round_step",
         grid=(steps,),
         in_specs=[
             pl.BlockSpec((tile_w, cap), row),

@@ -101,6 +101,7 @@ def weight_update(
 
     m_new, w = pl.pallas_call(
         _weight_update_kernel,
+        name="weight_update",
         grid=(steps,),
         in_specs=[
             pl.BlockSpec((tile_n, d), lambda i: (i, 0)),
