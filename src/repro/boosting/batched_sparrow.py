@@ -32,11 +32,11 @@ Deviations from the unbatched worker, both bounded and test-pinned:
 from __future__ import annotations
 
 import contextlib
-import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.boosting.scanner import (
     SampleState,
@@ -45,6 +45,7 @@ from repro.boosting.scanner import (
     reset_after_fire,
     reset_after_fruitless_pass,
     scan_chunk,
+    window_rows,
 )
 from repro.boosting.sparrow import (
     STUMP_EVAL_COST,
@@ -95,6 +96,16 @@ class BatchedSparrowState(NamedTuple):
 # per-worker select over a stacked pytree — the contract-level helper
 # from repro.core.worker, kept under its historical local name
 _bwhere = masked_rows
+
+#: layout of the (W, m, d) sample bins, major to minor: feature, worker,
+#: example. Adoption and the scan's weight refresh read a feature's bins
+#: over many examples; a resample writes one worker's (m, d) rows, and
+#: left to itself XLA keeps the bins worker-major for that write, then
+#: converts all of them (1.8 GB at W=16, m=200k, d=141) for the readers
+#: in every round, resample or not. Pinned where the resample writes,
+#: the one layout holds through the whole round step; it is also the
+#: TPU's own layout for the bins as a chunk argument.
+_BINS_LAYOUT = Layout(major_to_minor=(2, 0, 1))
 
 
 def common_prefix_len(a: StumpModel, b: StumpModel) -> jnp.ndarray:
@@ -196,9 +207,12 @@ class BatchedSparrowWorker(SparrowWorkerBase):
     ) -> tuple[BatchedSparrowState, jnp.ndarray, jnp.ndarray]:
         cfg = self.config
         m = cfg.sample_size
-        scan = functools.partial(scan_chunk, config=cfg.scanner)
+
+        def scan(scanner, sample, model, feat_mask, xb_c):
+            return scan_chunk(scanner, sample, model, feat_mask, config=cfg.scanner, xb_c=xb_c)
+
         scanner_s, sample_s, info = jax.vmap(scan)(
-            state.scanner, state.sample, state.model, state.feat_mask
+            state.scanner, state.sample, state.model, state.feat_mask, self._chunk_rows(state)
         )
         chunk = min(cfg.scanner.chunk_size, m)
         maskf = mask.astype(jnp.float32)
@@ -249,57 +263,95 @@ class BatchedSparrowWorker(SparrowWorkerBase):
         new_state = _bwhere(mask, new_state, state)
         return new_state, cost, fired
 
-    # ----- resample segment (rare; sequential over workers so the full
-    # disk pass never materializes a (W, n, T) intermediate, and in
-    # place, so the (W, ...) state is never copied) ---------------------
+    def _chunk_rows(self, state: BatchedSparrowState) -> jnp.ndarray | None:
+        """Each worker's next chunk of sample bins, (W, c, d), read with
+        slices so the bins keep the layout of ``_BINS_LAYOUT`` (a row
+        gather would want them rows-contiguous). A worker's chunk wraps
+        past the sample's end once a pass; only a round in which one
+        does reads the wrapping windows. None (scan_chunk gathers) where
+        a chunk is longer than the sample."""
+        c, m = self.config.scanner.chunk_size, self.config.sample_size
+        if c > m:
+            return None
+        xb, pos = state.sample.xb, state.scanner.pos
+
+        def heads():
+            x = with_layout_constraint(xb, _BINS_LAYOUT)
+            return jax.vmap(lambda x, p: jax.lax.dynamic_slice_in_dim(x, p, c))(x, pos)
+
+        def windows():
+            x = with_layout_constraint(xb, _BINS_LAYOUT)
+            return jax.vmap(window_rows, (0, 0, None))(x, pos, c)
+
+        return jax.lax.cond(jnp.any(pos > m - c), windows, heads)
+
+    # ----- resample segment (rare; sequential over the workers that
+    # resample, so the full disk pass never materializes a (W, n, T)
+    # intermediate, and in place, so the (W, ...) state is never copied;
+    # a round with no resample runs no trip) ----------------------------
     def resample_round(
         self, state: BatchedSparrowState, do: jnp.ndarray
     ) -> tuple[BatchedSparrowState, jnp.ndarray]:
-        cfg = self.config
+        # one trip per worker with ``do`` set, in ascending order; each
+        # writes that worker's rows of the leaves it changed, in place
+        w = do.shape[0]
+        rows = jnp.nonzero(do, size=w, fill_value=w)[0]
 
-        def _resample_one(st: BatchedSparrowState):
-            stale = st.disk_t < 0
-            t_from = jnp.maximum(st.disk_t, 0)
-            delta = predict_margin_delta(
-                st.model, self.xb, jnp.full((self.n,), t_from, jnp.int32)
-            )
-            evals = (
-                self.n * jnp.minimum(st.model.count - t_from, st.model.capacity)
-            ).astype(jnp.float32)
-            disk_margin = jnp.where(stale, 0.0, st.disk_margin) + delta
-            disk_t = st.model.count
-            key, sub = jax.random.split(st.key)
-            sample = draw_sample(sub, self.xb, self.y, st.model, disk_margin, cfg.sample_size)
-            cost = self.n * cfg.disk_read_cost + STUMP_EVAL_COST * evals
-            if cfg.parallel_sampler:
-                cost = jnp.maximum(cost - st.scan_since_resample, 0.0)
-            scanner = reset_after_fire(st.scanner, True, cfg.scanner)._replace(
-                pos=jnp.zeros((), jnp.int32)
-            )
-            new = st._replace(
-                sample=sample,
-                disk_margin=disk_margin,
-                disk_t=disk_t,
-                key=key,
-                needs_resample=jnp.zeros((), bool),
-                scanner=scanner,
-                resamples=st.resamples + 1,
-                sample_model_count=st.model.count,
-                scan_since_resample=jnp.zeros((), jnp.float32),
-            )
-            return new, jnp.asarray(cost, jnp.float32)
-
-        def _one(i, carry):
-            states, costs = carry
+        def _one(carry):
+            k, states, costs = carry
+            i = rows[k]
             st = jax.tree_util.tree_map(lambda a: a[i], states)
-            new, cost = jax.lax.cond(
-                do[i], _resample_one, lambda s: (s, jnp.zeros((), jnp.float32)), st
+            new, cost = self._resample_one(st)
+            states = jax.tree_util.tree_map(
+                lambda a, v, o: a if v is o else a.at[i].set(v), states, new, st
             )
-            states = jax.tree_util.tree_map(lambda a, v: a.at[i].set(v), states, new)
-            return states, costs.at[i].set(cost)
+            xb = with_layout_constraint(states.sample.xb, _BINS_LAYOUT)
+            states = states._replace(sample=states.sample._replace(xb=xb))
+            return k + 1, states, costs.at[i].set(cost)
 
-        costs = jnp.zeros(do.shape, jnp.float32)
-        return jax.lax.fori_loop(0, do.shape[0], _one, (state, costs))
+        n = jnp.sum(do, dtype=jnp.int32)
+        carry = (jnp.zeros((), jnp.int32), state, jnp.zeros((w,), jnp.float32))
+        _, state, costs = jax.lax.while_loop(lambda c: c[0] < n, _one, carry)
+        return state, costs
+
+    def _resample_one(
+        self, st: BatchedSparrowState
+    ) -> tuple[BatchedSparrowState, jnp.ndarray]:
+        """One worker's resample (``st`` is its slice of the stacked
+        state, without the worker axis): refresh its disk margins from
+        the stump count they are current to, draw a new sample, reset
+        the scanner; returns (new slice, cost)."""
+        cfg = self.config
+        stale = st.disk_t < 0
+        t_from = jnp.maximum(st.disk_t, 0)
+        delta = predict_margin_delta(
+            st.model, self.xb, jnp.full((self.n,), t_from, jnp.int32)
+        )
+        evals = (
+            self.n * jnp.minimum(st.model.count - t_from, st.model.capacity)
+        ).astype(jnp.float32)
+        disk_margin = jnp.where(stale, 0.0, st.disk_margin) + delta
+        disk_t = st.model.count
+        key, sub = jax.random.split(st.key)
+        sample = draw_sample(sub, self.xb, self.y, st.model, disk_margin, cfg.sample_size)
+        cost = self.n * cfg.disk_read_cost + STUMP_EVAL_COST * evals
+        if cfg.parallel_sampler:
+            cost = jnp.maximum(cost - st.scan_since_resample, 0.0)
+        scanner = reset_after_fire(st.scanner, True, cfg.scanner)._replace(
+            pos=jnp.zeros((), jnp.int32)
+        )
+        new = st._replace(
+            sample=sample,
+            disk_margin=disk_margin,
+            disk_t=disk_t,
+            key=key,
+            needs_resample=jnp.zeros((), bool),
+            scanner=scanner,
+            resamples=st.resamples + 1,
+            sample_model_count=st.model.count,
+            scan_since_resample=jnp.zeros((), jnp.float32),
+        )
+        return new, jnp.asarray(cost, jnp.float32)
 
     # ----- adoption (interrupt + replace (H, L)) -----------------------
     def adopt_batch(

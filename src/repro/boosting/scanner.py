@@ -151,6 +151,20 @@ def reset_after_fruitless_pass(state: ScannerState) -> ScannerState:
     )
 
 
+def window_rows(xb: jnp.ndarray, pos: jnp.ndarray, c: int) -> jnp.ndarray:
+    """Rows ``(pos + arange(c)) % m`` of ``xb`` (m, d), for ``c <= m``,
+    read with slices: a row gather would need the rows contiguous in
+    memory, which the batched worker's sample bins are not (they are
+    laid out for the margin passes, a feature at a time)."""
+    m = xb.shape[0]
+    head = jax.lax.dynamic_slice_in_dim(xb, jnp.minimum(pos, m - c), c)
+    # the wrapping windows start in the last c rows: read them from the
+    # last c rows followed by the first c
+    ring = jnp.concatenate([xb[m - c :], xb[:c]])
+    wrap = jax.lax.dynamic_slice_in_dim(ring, jnp.maximum(pos - (m - c), 0), c)
+    return jnp.where(pos > m - c, wrap, head)
+
+
 @functools.partial(jax.jit, static_argnames=("config",))
 def scan_chunk(
     scanner: ScannerState,
@@ -158,6 +172,7 @@ def scan_chunk(
     model: StumpModel,
     feat_mask: jnp.ndarray,
     config: ScannerConfig,
+    xb_c: jnp.ndarray | None = None,
 ) -> tuple[ScannerState, SampleState, FireInfo]:
     """Process one chunk of the in-memory sample.
 
@@ -165,6 +180,8 @@ def scan_chunk(
         feat_mask: (d,) bool — features this worker owns (feature-based
             parallelization, paper §4). Candidates on un-owned features
             never fire.
+        xb_c: (chunk_size, d) the chunk's rows of ``sample.xb``, where
+            the caller has read them already; gathered here by default.
     """
     m = sample.xb.shape[0]
     c = config.chunk_size
@@ -174,7 +191,8 @@ def scan_chunk(
     valid = offs < remaining
     idx = (scanner.pos + offs) % m
 
-    xb_c = sample.xb[idx]  # (c, d)
+    if xb_c is None:
+        xb_c = sample.xb[idx]  # (c, d)
     y_c = sample.y[idx]
 
     # --- lazy incremental weight refresh (UPDATEWEIGHT) ---

@@ -40,13 +40,13 @@ the simulator, so benchmarks and analysis are substrate-agnostic.
 Dispatch chunking: at small per-round compute the wall clock is one
 Python dispatch + one host sync *per round*. The engine therefore runs
 :attr:`EngineConfig.rounds_per_dispatch` rounds per jitted call inside
-a ``lax.scan``, returning the per-round :class:`RoundInfo` stacked over
-the chunk — one dispatch and at most one device sync per chunk, while
-per-round history and the *exact* round that crossed
+a ``lax.while_loop``, returning the per-round :class:`RoundInfo` stacked
+over the chunk — one dispatch and at most one device sync per chunk,
+while per-round history and the *exact* round that crossed
 ``target_certificate`` are still recovered on the host. When a target
-is set, a ``done`` flag inside the scan freezes the carried state on
-the crossing round, so the final state is bit-identical to an
-unchunked (``rounds_per_dispatch=1``) run for every chunk size.
+is set, the loop stops on the crossing round, so the final state is
+bit-identical to an unchunked (``rounds_per_dispatch=1``) run for
+every chunk size.
 
 Fidelity level 3 — the device-sharded substrate: when
 :attr:`EngineConfig.mesh` names a multi-device ``workers`` mesh,
@@ -427,7 +427,7 @@ class EngineConfig:
     seed: int = 0
     #: record per-worker certificate changes into SimResult.history
     record_history: bool = True
-    #: rounds advanced per jitted dispatch (``lax.scan`` chunk). 1 =
+    #: rounds advanced per jitted dispatch (one loop per chunk). 1 =
     #: the old one-dispatch-per-round behavior; larger chunks amortize
     #: Python dispatch + host sync without changing any protocol
     #: semantics (exact rounds-to-target and per-round history are
@@ -1107,64 +1107,73 @@ class TMSNEngine:
         self._shared = shared_data(worker)
 
     # ------------------------------------------------------------------
-    # dispatch chunking: K rounds per jitted call via lax.scan
+    # dispatch chunking: K rounds per jitted call
     # ------------------------------------------------------------------
-    def _chunk_body(self, step, any_reduce):
-        """Scan body ``(state, done), _ -> ((state, done), RoundInfo)``.
+    def _chunk_rounds(self, step, any_reduce, state: EngineState, length: int):
+        """Run ``length`` rounds of ``step``; returns the final state and
+        the :class:`RoundInfo` of each round, stacked over ``length``.
 
         ``step`` is the (possibly shard-mapped) single-round step;
         ``any_reduce`` turns a (local) boolean vector into a scalar
         "any worker, any shard" — ``jnp.any`` on one device, a psum on
-        the sharded engine. When ``target_certificate`` is set, ``done``
-        freezes the carried state on the crossing round so the final
-        state is identical to an unchunked run for every chunk size.
+        the sharded engine. When ``target_certificate`` is set, the
+        chunk stops on the crossing round so the final state is
+        identical to an unchunked run for every chunk size.
         """
         target = self.config.target_certificate
 
-        def frozen(state):
-            # post-crossing rounds: state passes through untouched and
-            # the round reports no changes (so host history/stop logic
-            # sees the crossing round as the last live one)
-            info = RoundInfo(
-                certs=state.certs,
-                changed=jnp.zeros_like(state.alive),
-                clock=state.clock,
-                alive=state.alive,
+        # A loop that exits on the crossing round, not a cond per round
+        # that either runs the step or passes the state through: such a
+        # cond makes XLA copy every state leaf the step writes (the
+        # worker's sample included) in every round. `done` derives from
+        # an all-shard reduction, so every device runs the same rounds
+        # and the collectives inside stay uniform.
+        def frozen_info(st):
+            # the rounds after the crossing report the state as it stands
+            # and no changes (so host history/stop logic sees the crossing
+            # round as the last live one)
+            return RoundInfo(
+                certs=st.certs,
+                changed=jnp.zeros_like(st.alive),
+                clock=st.clock,
+                alive=st.alive,
             )
-            return state, info
 
-        def body(carry, _):
-            state, done = carry
+        def body(carry):
+            k, st, _, infos = carry
+            st, info = step(st)
+            infos = jax.tree_util.tree_map(lambda a, v: a.at[k].set(v), infos, info)
             if target is None:
-                new_state, info = step(state)
-            else:
-                # cond, not select: once done, the remaining rounds of
-                # the chunk skip the whole step (worker scan, gossip
-                # collectives, ring writes) instead of computing and
-                # discarding it. `done` derives from an all-shard
-                # reduction, so every device takes the same branch and
-                # the collectives inside stay uniform.
-                with jax.named_scope(telemetry.FREEZE):
-                    new_state, info = jax.lax.cond(done, frozen, step, state)
-                done = done | any_reduce(info.alive & (info.certs <= target))
-            return (new_state, done), info
+                return k + 1, st, jnp.zeros((), bool), infos
+            return k + 1, st, any_reduce(info.alive & (info.certs <= target)), infos
 
-        return body
+        infos = jax.tree_util.tree_map(
+            lambda a: jnp.zeros((length,) + a.shape, a.dtype), frozen_info(state)
+        )
+        carry = (jnp.zeros((), jnp.int32), state, jnp.zeros((), bool), infos)
+        ran, state, _, infos = jax.lax.while_loop(
+            lambda c: (c[0] < length) & ~c[2], body, carry
+        )
+        if target is None:
+            return state, infos
+        with jax.named_scope(telemetry.FREEZE):
+            live = jnp.arange(length) < ran
+            infos = jax.tree_util.tree_map(
+                lambda a, f: jnp.where(live.reshape((length,) + (1,) * f.ndim), a, f[None]),
+                infos,
+                frozen_info(state),
+            )
+        return state, infos
 
     def _build_chunk(self, length: int, state: EngineState):
         """Compiled ``state -> (state, RoundInfo stacked over length)``
         for states shaped like ``state``; the sharded engine overrides
-        this to run the scan inside ``shard_map``. The worker's shared
+        this to run the rounds inside ``shard_map``. The worker's shared
         read-only data enters as an argument, so it is never baked into
         the program as a constant."""
-        body = self._chunk_body(self._round_step, jnp.any)
-
         def chunk(state: EngineState, shared: Any):
             with bind_shared_data(self.worker, shared):
-                (state, _), infos = jax.lax.scan(
-                    body, (state, jnp.zeros((), bool)), None, length=length
-                )
-            return state, infos
+                return self._chunk_rounds(self._round_step, jnp.any, state, length)
 
         step = _compile(jax.jit(chunk), state, self._shared)
         return lambda state: step(state, self._shared)
@@ -1350,12 +1359,9 @@ class TMSNEngine:
         with jax.named_scope(telemetry.RESAMPLE):
             if self._has_resample:
                 need = self.worker.needs_resample(wstate) & active
-                wstate, resample_cost = jax.lax.cond(
-                    jnp.any(need),
-                    lambda op: self.worker.resample_round(op[0], op[1]),
-                    lambda op: (op[0], jnp.zeros((w,), jnp.float32)),
-                    (wstate, need),
-                )
+                # no guard: the hook runs no work for workers not in
+                # `need`, and a cond would copy the state it carries
+                wstate, resample_cost = self.worker.resample_round(wstate, need)
                 scan_mask = active & ~need
             else:
                 resample_cost = jnp.zeros((w,), jnp.float32)
@@ -1599,7 +1605,9 @@ class TMSNEngine:
         :mod:`repro.core.telemetry`: the spans ``tmsn.init``,
         ``tmsn.dispatch``, ``tmsn.fetch``, ``tmsn.host`` and
         ``tmsn.finalize`` under ``tmsn.run``, and the counters
-        ``chunks``, ``rounds``, ``host_fetches`` and ``chunk_compiles``."""
+        ``chunks``, ``rounds``, ``host_fetches``, ``chunk_compiles``,
+        ``adoptions`` and, for a worker state with a ``resamples`` field,
+        ``resamples``."""
         with telemetry.run_scope():
             return self._run()
 
@@ -1695,6 +1703,10 @@ class TMSNEngine:
             dropped_injected=_to_host(state.dropped_inj),
             corrupt_rejected=_to_host(state.corrupt_rej),
         )
+        telemetry.count("adoptions", traffic.accepted)
+        resamples = getattr(state.worker, "resamples", None) if self._has_resample else None
+        if resamples is not None:
+            telemetry.count("resamples", int(np.sum(_to_host(resamples))))
         # a join "happened" when its spare went live strictly after
         # round 0 and before the run ended (k=1 joins are full members
         # from the start, so a k=1 run reports 0 — matching the plain

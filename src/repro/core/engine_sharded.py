@@ -76,11 +76,11 @@ What changes relative to the single-device engine:
     pod, written by that pod's tier-1 gather plus the (globally
     identical) tier-2 flushes;
   * dispatch is chunked (``EngineConfig.rounds_per_dispatch``): the
-    whole ``lax.scan`` over K rounds runs inside ONE ``shard_map``
-    region, so per-chunk Python dispatch + host sync amortize over K
-    rounds and the per-round collectives stay inside the compiled
-    program. Target-crossing detection inside the scan uses a psum
-    across shards;
+    whole loop over K rounds runs inside ONE ``shard_map`` region, so
+    per-chunk Python dispatch + host sync amortize over K rounds and
+    the per-round collectives stay inside the compiled program.
+    Target-crossing detection inside the loop uses a psum across
+    shards;
   * **sparse in-flight state** (``EngineConfig.inflight_capacity > 0``)
     swaps the per-shard ``(W_local, W, D)`` buffer for bounded
     destination-sharded pending queues ``(W_local, C)`` fed by the same
@@ -235,7 +235,7 @@ class ShardedTMSNEngine(TMSNEngine):
 
     # ------------------------------------------------------------------
     def _build_chunk(self, length: int, state: EngineState):
-        """Chunk dispatcher: the whole K-round ``lax.scan`` runs inside
+        """Chunk dispatcher: the whole K-round loop runs inside
         one ``shard_map`` region (collectives and the cross-shard
         target-crossing psum stay inside the compiled program)."""
         mesh = self.config.mesh
@@ -285,14 +285,9 @@ class ShardedTMSNEngine(TMSNEngine):
             return jax.lax.psum(jnp.any(x).astype(jnp.int32), axes) > 0
 
         def chunk_local(state: EngineState, consts: _ShardConsts, shared):
-            body = self._chunk_body(
-                lambda st: self._sharded_round_step(st, consts), _any_shard
-            )
+            step = lambda st: self._sharded_round_step(st, consts)
             with bind_shared_data(self.worker, shared):
-                (state, _), infos = jax.lax.scan(
-                    body, (state, jnp.zeros((), bool)), None, length=length
-                )
-            return state, infos
+                return self._chunk_rounds(step, _any_shard, state, length)
 
         # the worker's shared data is replicated: every device reads all of it
         shared_specs = jax.tree_util.tree_map(lambda _: P(), self._shared)
@@ -498,12 +493,9 @@ class ShardedTMSNEngine(TMSNEngine):
         with jax.named_scope(telemetry.RESAMPLE):
             if self._has_resample:
                 need = self.worker.needs_resample(wstate) & active
-                wstate, resample_cost = jax.lax.cond(
-                    jnp.any(need),
-                    lambda op: self.worker.resample_round(op[0], op[1]),
-                    lambda op: (op[0], jnp.zeros((wl,), jnp.float32)),
-                    (wstate, need),
-                )
+                # no guard: the hook runs no work for workers not in
+                # `need`, and a cond would copy the state it carries
+                wstate, resample_cost = self.worker.resample_round(wstate, need)
                 scan_mask = active & ~need
             else:
                 resample_cost = jnp.zeros((wl,), jnp.float32)

@@ -46,11 +46,11 @@ FINALIZE = "tmsn.finalize"  # from the loop's end to the returned result
 # --- named scopes of the round step ----------------------------------------
 DELIVER = "tmsn.deliver"  # arrivals due this round, accept gate, credit
 ADOPT = "tmsn.adopt"  # the payload lookup and the adoption cond
-RESAMPLE = "tmsn.resample"  # the resample cond
+RESAMPLE = "tmsn.resample"  # the resample segment of the workers that need one
 SCAN = "tmsn.scan"  # certificates, the worker segment, certificates
 BROADCAST = "tmsn.broadcast"  # pushes into the in-flight state, ring write
 GOSSIP = "tmsn.gossip"  # the sharded engine's all_gathers
-FREEZE = "tmsn.freeze"  # the to-target cond that freezes a finished chunk
+FREEZE = "tmsn.freeze"  # the rows of a to-target chunk after its crossing round
 SCOPES = (DELIVER, ADOPT, RESAMPLE, SCAN, BROADCAST, GOSSIP, FREEZE)
 
 #: run records kept, newest last

@@ -157,7 +157,9 @@ class BatchedTMSNWorker(Protocol):
 
     def resample_round(self, state: Any, do: jnp.ndarray) -> tuple[Any, jnp.ndarray]:
         """Spend the segment of every worker where ``do`` on a resample;
-        returns (new_state, cost (W,))."""
+        returns (new_state, cost (W,)). The engines call it every round
+        without a guard, so it must be cheap where ``do`` is all False:
+        a round with no resample should touch none of the state."""
         return state, jnp.zeros_like(self.certificates(state), dtype=jnp.float32)
 
     # ----- optional: payload hooks (derived defaults) ------------------
@@ -198,12 +200,19 @@ class BatchedTMSNWorker(Protocol):
 def masked_rows(cond: jnp.ndarray, new: Any, old: Any) -> Any:
     """Per-worker select over a stacked pytree: broadcast the ``(W,)``
     cond over each leaf's trailing dims. The canonical way to satisfy
-    the contract's "masked-out workers come back bitwise unchanged"."""
-    return jax.tree_util.tree_map(
-        lambda a, b: jnp.where(cond.reshape(cond.shape + (1,) * (a.ndim - 1)), a, b),
-        new,
-        old,
-    )
+    the contract's "masked-out workers come back bitwise unchanged".
+
+    A leaf that is the very same value in ``new`` and ``old`` (a field
+    the caller left alone) comes back as it is: ``where(c, x, x)`` is
+    ``x`` bitwise, and a leaf returned untouched is one an enclosing
+    ``lax.cond`` forwards instead of copying through its outputs."""
+
+    def select(a, b):
+        if a is b:
+            return a
+        return jnp.where(cond.reshape(cond.shape + (1,) * (a.ndim - 1)), a, b)
+
+    return jax.tree_util.tree_map(select, new, old)
 
 
 def has_resample_hooks(worker: BatchedTMSNWorker) -> bool:

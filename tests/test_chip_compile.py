@@ -127,3 +127,61 @@ def test_queue_ingest(one_chip):
         ((W, m), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("target", [None, -0.45], ids=["rounds", "to-target"])
+def test_engine_chunk_never_copies_the_sample_bins(one_chip, monkeypatch, target):
+    """The Sparrow engine's chunk program at the benchmark's widths (16
+    workers, 141 features, 2048-example scan chunks; a smaller sample and
+    disk set): no computation but the entry copies the (W, m, d) sample
+    bins, and they keep one layout. The entry copies the chunk's argument
+    once, as it is not donated. A per-round cond that carries the bins,
+    or phases that want them in different layouts, put such copies inside
+    the round step, where they run every round."""
+    from repro.boosting import BatchedSparrowWorker, SparrowConfig
+    from repro.boosting.scanner import ScannerConfig
+    from repro.core.engine import EngineConfig, TMSNEngine
+    from repro.core.worker import bind_shared_data
+    from repro.kernels import edge_scan, round_step, weight_update
+    from repro.launch.hlo_analysis import parse_instruction, split_computations
+
+    for mod in (edge_scan, round_step, weight_update):
+        monkeypatch.setattr(mod, "resolve_interpret", lambda interpret: False)
+    w, m, d, n = 16, 4096, 141, 32768
+    kx, ky = jax.random.split(jax.random.PRNGKey(0))
+    xb = jax.random.randint(kx, (n, d), 0, 4, jnp.int32)
+    y = jnp.where(jax.random.bernoulli(ky, 0.01, (n,)), 1.0, -1.0)
+    cfg = SparrowConfig(
+        sample_size=m,
+        capacity=256,
+        scanner=ScannerConfig(chunk_size=2048, num_bins=4, use_kernel=True),
+        n_workers=w,
+    )
+    worker = BatchedSparrowWorker(xb, y, cfg)
+    eng = TMSNEngine(
+        worker,
+        EngineConfig(n_workers=w, max_rounds=16, seed=1, rounds_per_dispatch=8, fault_spec="",
+                     inflight_capacity=64, target_certificate=target,
+                     record_history=target is not None),
+    )
+    put = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    state = jax.tree_util.tree_map(put, jax.eval_shape(eng._init_state))
+    shared = (put(xb), put(y))
+
+    def chunk(state, shared):
+        with bind_shared_data(worker, shared):
+            return eng._chunk_rounds(eng._round_step, jnp.any, state, 8)
+
+    text = jax.jit(chunk).lower(state, shared).compile().as_text()
+    bins = f"s32[{w},{m},{d}]"
+    comps, entry = split_computations(text)
+    copies, layouts = {}, set()
+    for name, lines in comps.items():
+        for line in lines:
+            ins = parse_instruction(line)
+            if ins is not None and ins.type_text.startswith(bins):
+                layouts.add(ins.type_text.split(":")[0])
+                if ins.opcode in ("copy", "copy-start"):
+                    copies[name] = copies.get(name, 0) + 1
+    assert copies == {entry: 1}
+    assert len(layouts) == 1, layouts

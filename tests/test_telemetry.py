@@ -7,6 +7,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.boosting import BatchedSparrowWorker, SparrowConfig
@@ -242,6 +243,32 @@ class TestEngineTelemetry:
             if s.parent is not None:
                 _, lo, hi = near[0]
                 assert any(n == s.parent and a <= lo and hi <= b for n, a, b in events)
+
+
+    def test_adoptions_and_resamples_are_counted(self, data):
+        """``adoptions`` is the result's accepted count and ``resamples``
+        what the worker state counts, read once the run has ended (an ESS
+        threshold of 1 resamples after every fire)."""
+        xtr, ytr, _, _ = data
+        cfg = SparrowConfig(
+            sample_size=256,
+            capacity=16,
+            scanner=ScannerConfig(chunk_size=128, num_bins=8, gamma0=0.25),
+            n_workers=W,
+            ess_threshold=1.0,
+        )
+        eng = make_engine(
+            BatchedSparrowWorker(xtr, ytr, cfg),
+            EngineConfig(n_workers=W, max_rounds=16, seed=0, rounds_per_dispatch=4,
+                         fault_spec="", inflight_capacity=8, record_history=False),
+        )
+        res = eng.run()
+        rec = telemetry.runs(last=1)[0]
+        state = eng._init_state()
+        for _ in range(4):
+            state, _ = eng._chunk_fn(4, state)(state)
+        assert rec.counters["adoptions"] == res.messages_accepted > 0
+        assert rec.counters["resamples"] == int(np.sum(state.worker.resamples)) > 0
 
 
 @pytest.mark.skipif(not sharded_engine_available(4), reason="needs 4 devices")
