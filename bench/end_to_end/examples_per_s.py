@@ -1,10 +1,9 @@
-"""Examples scanned per host-clock second: W x min(chunk, sample) x rounds,
-summed over every training in the window, over their total time."""
+"""Examples processed per host-clock second: the examples of every
+training in the window over their total time. Each worker family defines
+its example and counts it in its ``summary`` (``bench/families/<family>.py``):
+for Sparrow, a sample row scanned, W x min(chunk, sample) a round."""
 
 
 def read(ctx):
     t = ctx["trainings"]
-    cfg = ctx["config"]
-    rows = min(cfg["scanner"]["chunk_size"], cfg["sparrow"]["sample_size"])
-    examples = cfg["engine"]["n_workers"] * rows * sum(x["rounds"] for x in t)
-    return examples / sum(x["seconds"] for x in t)
+    return sum(x["examples"] for x in t) / sum(x["seconds"] for x in t)

@@ -10,17 +10,43 @@ name, and every metric by its name too: an end-to-end metric is read by
 ``bench/layer_metrics/<name>.py``. Each reader has ``read(ctx)`` and
 returns a number, or None where it finds nothing to read.
 
-A run generates the configuration's data set on the device, draws the
-engine's seed from ``--seed`` (the traffic's ``engine_seeds`` of them,
-one by default), builds the worker and an engine for each through the
-program's entry point (``make_engine(BatchedSparrowWorker(...),
-EngineConfig(...))``), warms each up with one whole training (the
-compile, counted as set-up), then runs trainings back to back for
-``--seconds``, the engines in turn. With ``--trace 1`` that window is
-traced and the per-layer metrics are reported instead of the end-to-end
-ones. Once the window has closed and the device's peak memory has been
-read, the plain reference (``bench/reference.py``) follows the window's
-last training and ``bench/compare.py`` decides ``correct``.
+The configuration's ``family`` key names the worker family, found the
+same way: ``bench/families/<family>.py``. A configuration without it is
+refused. The harness knows nothing of any family, and calls these six
+functions of its module:
+
+``make_data(cfg)``
+    the configuration's data set, made on the device (anything the
+    family's other functions take as ``data``).
+``build_engine(cfg, traffic, data, seed, chips)``
+    an engine built through the program's entry point for engine seed
+    ``seed`` on ``chips`` chips; the harness calls its ``run()``, whose
+    result ``res`` holds ``final_models``.
+``summary(res, cfg, traffic)``
+    one training as a dict: ``rounds``, ``certs``, ``accepted`` (a
+    window's training has to repeat its engine's warm-up in these
+    three), ``failed`` (bool) and ``examples``, the examples the
+    training processed, counted as the family defines an example.
+``observed(res)``
+    what the training produced, as host arrays, for ``compare``.
+``reference(cfg, traffic, data, seed)``
+    the plain reference following the training of engine seed ``seed``:
+    a dict with ``rounds``, and ``exact_until`` where the family's
+    reference can stop being exact (None: exact throughout).
+``compare(prog, ref, traffic)``
+    the numbers compared, keyed as ``bench/limits/<config>.json`` keys
+    their limits; among them ``decisions_differ``, to which the harness
+    adds each window training that does not repeat its engine's warm-up.
+
+A run makes the configuration's data set, draws the engine's seed from
+``--seed`` (the traffic's ``engine_seeds`` of them, one by default),
+builds an engine for each, warms each up with one whole training (the
+compile, counted as set-up), then runs trainings back to back, the
+engines in turn, until ``--seconds`` have passed and the turn is whole.
+With ``--trace 1`` that window is traced and the per-layer metrics are
+reported instead of the end-to-end ones. Once the window has closed and
+the device's peak memory has been read, the family's reference follows
+the window's last training and its comparison decides ``correct``.
 
 The last line of standard output is the JSON result; the last lines of
 standard error are the numbers compared, each beside its limit. Without
@@ -90,76 +116,34 @@ def engine_seed(seed: int, k: int = 0) -> int:
     return int.from_bytes(h[:4], "little") >> 2
 
 
-def load_reader(kind: str, name: str):
-    path = BENCH / kind / f"{name}.py"
+def load_module(path: Path, name: str):
+    """The module at ``path``; its directory joins ``sys.path`` so it can
+    import its neighbours."""
     if str(path.parent) not in sys.path:
         sys.path.insert(0, str(path.parent))
-    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}".replace(".", "_"), path)
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(kind: str, name: str):
+    return load_module(BENCH / kind / f"{name}.py", f"bench_{kind}_{name}").read
+
+
+def load_family(name: str | None, root: Path = BENCH):
+    """The worker family ``name``: the module ``<root>/families/<name>.py``."""
+    if not name:
+        raise SystemExit("bench: the configuration names no worker family (its key `family`)")
+    path = root / "families" / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"bench: unknown worker family {name!r}: no file {path}")
+    return load_module(path, f"bench_families_{name}")
 
 
 # ----------------------------------------------------------------------
 # the system under test
 # ----------------------------------------------------------------------
-
-
-def build_engine(cfg: dict, traffic: dict, xtr, ytr, seed: int, chips: int):
-    """The program's own entry point, with every setting the cell
-    depends on given explicitly (``EngineConfig`` otherwise reads
-    ``REPRO_*`` environment variables)."""
-    from repro.boosting import BatchedSparrowWorker, SparrowConfig
-    from repro.boosting.scanner import ScannerConfig
-    from repro.core.engine import EngineConfig, make_engine
-
-    s, e = cfg["sparrow"], cfg["engine"]
-    sc = dict(cfg["scanner"], num_bins=cfg["data"]["num_bins"])
-    worker = BatchedSparrowWorker(
-        xtr,
-        ytr,
-        SparrowConfig(
-            sample_size=s["sample_size"],
-            capacity=s["capacity"],
-            scanner=ScannerConfig(**sc),
-            ess_threshold=s["ess_threshold"],
-            keep_gamma_on_fire=s["keep_gamma_on_fire"],
-            n_workers=e["n_workers"],
-            ownership_redundancy=s["ownership_redundancy"],
-            mem_read_cost=s["mem_read_cost"],
-            disk_read_cost=s["disk_read_cost"],
-            parallel_sampler=s["parallel_sampler"],
-        ),
-    )
-    mesh = None
-    if chips > 1:
-        from repro.launch.mesh import make_worker_mesh
-
-        mesh = make_worker_mesh(chips)
-    to_target = traffic["kind"] == "to_target"
-    config = EngineConfig(
-        n_workers=e["n_workers"],
-        eps=e["eps"],
-        max_rounds=traffic["max_rounds"] if to_target else traffic["rounds"],
-        delay_rounds=e["delay_rounds"],
-        target_certificate=traffic["target_certificate"] if to_target else None,
-        seed=seed,
-        record_history=traffic["record_history"],
-        rounds_per_dispatch=e["rounds_per_dispatch"],
-        gossip_mode=e["gossip_mode"],
-        gossip_top_k=e["gossip_top_k"],
-        cross_pod_every_k=e["cross_pod_every_k"],
-        cross_pod_top_k=e["cross_pod_top_k"],
-        inflight_capacity=e["inflight_capacity"],
-        round_step_impl=e["round_step_impl"],
-        control_plane=e["control_plane"],
-        spare_slots=e["spare_slots"],
-        fault_spec=e["fault_spec"],
-        publish_every_k=e["publish_every_k"],
-        publish_eps=e["publish_eps"],
-        mesh=mesh,
-    )
-    return make_engine(worker, config)
 
 
 def one_training(engine):
@@ -171,38 +155,6 @@ def one_training(engine):
     res = engine.run()
     jax.block_until_ready(res.final_models)
     return res, time.perf_counter() - t0
-
-
-def summary(res, traffic: dict) -> dict:
-    import numpy as np
-
-    certs = np.asarray(res.final_certificates, np.float64)
-    best = float(certs.min())
-    reached = traffic["kind"] != "to_target" or best <= float(np.float32(traffic["target_certificate"]))
-    return {
-        "rounds": int(res.rounds),
-        "certs": certs.tolist(),
-        "accepted": int(res.messages_accepted),
-        "failed": (not math.isfinite(best)) or not reached,
-    }
-
-
-def observed(res) -> dict:
-    """What the training produced, as host arrays, for the comparison."""
-    import numpy as np
-
-    models = {
-        k: np.stack([np.asarray(getattr(m, k)) for m in res.final_models])
-        for k in ("feat", "thr", "sign", "alpha", "count")
-    }
-    return {
-        "rounds": int(res.rounds),
-        "history": list(res.history),
-        "final_certificates": np.asarray(res.final_certificates, np.float64),
-        "models": models,
-        "accepted": int(res.messages_accepted),
-        "evicted": int(res.messages_evicted),
-    }
 
 
 # ----------------------------------------------------------------------
@@ -239,19 +191,17 @@ def run_cell(
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         log(f"bench: compile cache {cache}")
 
-    import reference
-    from compare import compare
-    from splice_data import make_data
+    family = spec.get("family") or load_family(cfg.get("family"))
 
     # the traffic's ``engine_seeds`` engines, each warmed by one whole
     # training; the window runs their trainings in turn
     eseeds = [engine_seed(seed, k) for k in range(int(traffic.get("engine_seeds", 1)))]
-    xtr, ytr, _, _ = jax.block_until_ready(make_data(cfg))
+    data = jax.block_until_ready(family.make_data(cfg))
     engines, firsts, warm_s = [], [], []
     for es in eseeds:
-        engines.append(build_engine(cfg, traffic, xtr, ytr, es, chips))
+        engines.append(family.build_engine(cfg, traffic, data, es, chips))
         warm, dt = one_training(engines[-1])
-        firsts.append(summary(warm, traffic))
+        firsts.append(family.summary(warm, cfg, traffic))
         warm_s.append(dt)
         del warm
     setup_s = time.perf_counter() - T_START
@@ -272,8 +222,10 @@ def run_cell(
         with jax.profiler.TraceAnnotation("training"):
             last, dt = one_training(engines[k])
         with jax.profiler.TraceAnnotation("between trainings"):
-            trainings.append(dict(summary(last, traffic), seconds=dt, engine=k))
-        if time.perf_counter() - t_window >= seconds:
+            trainings.append(dict(family.summary(last, cfg, traffic), seconds=dt, engine=k))
+        # the window closes on a whole turn, so every engine seed weighs
+        # the same in every run and no run stops early for a slow training
+        if k == len(engines) - 1 and time.perf_counter() - t_window >= seconds:
             break
     window_s = time.perf_counter() - t_window
     if trace:
@@ -282,26 +234,21 @@ def run_cell(
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in used)
     log(f"bench: {len(trainings)} trainings in {window_s:.3f} s; rounds {trainings[-1]['rounds']}; "
         f"best certificate {min(trainings[-1]['certs']):.6f}")
+    log("bench: training seconds " + " ".join(f"{t['engine']}:{t['seconds']:.4f}" for t in trainings))
 
-    prog = observed(last)
+    prog = family.observed(last)
     del last, engines
     gc.collect()
 
     # --- correct: the plain reference follows the same training ---
-    to_target = traffic["kind"] == "to_target"
-    params = reference.params_from_config(cfg)
     t_ref = time.perf_counter()
-    ref = reference.train(
-        xtr, ytr, eseeds[trainings[-1]["engine"]], params,
-        max_rounds=traffic["max_rounds"] if to_target else traffic["rounds"],
-        target=traffic["target_certificate"] if to_target else None,
-    )
-    numbers = compare(prog, ref, history=traffic["record_history"])
+    ref = family.reference(cfg, traffic, data, eseeds[trainings[-1]["engine"]])
+    numbers = family.compare(prog, ref, traffic)
     decided = lambda t: (t["rounds"], t["certs"], t["accepted"])
     repeats = [t for t in trainings if decided(t) != decided(firsts[t["engine"]])]
     numbers["decisions_differ"] += len(repeats)
     log(f"bench: reference {time.perf_counter() - t_ref:.3f} s, {ref['rounds']} rounds, "
-        f"exact until round {ref['exact_until']}")
+        f"exact until round {ref.get('exact_until')}")
     limits = spec["limits"]
     checks = {k: {"value": v, "limit": limits[k]} for k, v in numbers.items()}
     correct = all(math.isfinite(c["value"]) and c["value"] <= c["limit"] for c in checks.values())
